@@ -5,8 +5,8 @@ compiled, every further job admitted into that bucket runs WITHOUT tracing or
 compiling anything — submit latency drops from the XLA-compile regime
 (tens of seconds on the secure path) to the steady dispatch regime
 (milliseconds). This benchmark measures exactly that, on a real service over
-a forced multi-host-device mesh in a SUBPROCESS (device-count forcing must
-precede jax init; same pattern as `bench_sharded_state`):
+a multi-device mesh (`benchmarks/multidevice.py`: every attached chip in this
+process, or forced host devices in a CPU child):
 
   * COLD job — first k-means submit into an empty cache: latency includes
     every chunk program compile (runner-cache misses > 0);
@@ -27,26 +27,19 @@ documented there; uploaded by the CI bench-smoke lane).
 
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-import textwrap
+from benchmarks.multidevice import run_section
 
 # Filled by run(); serialized by benchmarks/run.py into BENCH_service.json.
 LAST_METRICS: dict = {}
 
-_MARKER = "===BENCH_SERVICE_JSON==="
-
-_SERVICE_CHILD = """
-import json, time
+_SERVICE_SECTION = """
+import time
 import numpy as np
 import jax, jax.numpy as jnp
 from repro.compat import make_mesh
 from repro.core.shuffle import SecureShuffleConfig
 from repro.serve.service import SecureJobService
 
-n_dev, n_items, depths, max_rounds, max_chunk = {n_dev}, {n_items}, {depths}, {max_rounds}, {max_chunk}
 mesh = make_mesh((n_dev,), ("data",))
 secure = SecureShuffleConfig(
     key_words=jnp.arange(8, dtype=jnp.uint32),
@@ -60,12 +53,12 @@ def points(n, seed):
     return (c[rng.integers(0, 4, size=n)]
             + rng.normal(scale=0.05, size=(n, 2))).astype(np.float32)
 
-out = {{}}
+out = {}
 # COLD: empty cache — latency includes every chunk-program compile
 h = svc.submit_kmeans(points(n_items, seed=0), 4, max_rounds=max_rounds)
 h.result(1800)
-out["cold"] = {{"latency_s": h.latency_s, "runner_misses": h.runner_misses,
-               "n_iter": h.result()["n_iter"]}}
+out["cold"] = {"latency_s": h.latency_s, "runner_misses": h.runner_misses,
+               "n_iter": h.result()["n_iter"]}
 assert h.runner_misses > 0, "cold job must build runners"
 
 # WARM: different data AND different real size, same geometric bucket —
@@ -75,17 +68,17 @@ h2 = svc.submit_kmeans(points(max(4, n_items - n_dev), seed=1), 4,
                        max_rounds=max_rounds)
 h2.result(1800)
 new_compiles = svc.cache.compile_cache_size() - compiles_before
-assert h2.runner_misses == 0, f"warm job missed the cache: {{h2.runner_misses}}"
-assert new_compiles == 0, f"warm job compiled {{new_compiles}} programs"
+assert h2.runner_misses == 0, f"warm job missed the cache: {h2.runner_misses}"
+assert new_compiles == 0, f"warm job compiled {new_compiles} programs"
 assert h2.latency_s * 10.0 <= h.latency_s, (
-    f"warm submit latency {{h2.latency_s:.4f}}s not >= 10x below cold "
-    f"{{h.latency_s:.4f}}s")
-out["warm"] = {{"latency_s": h2.latency_s, "runner_misses": h2.runner_misses,
-               "new_compiles": new_compiles}}
+    f"warm submit latency {h2.latency_s:.4f}s not >= 10x below cold "
+    f"{h.latency_s:.4f}s")
+out["warm"] = {"latency_s": h2.latency_s, "runner_misses": h2.runner_misses,
+               "new_compiles": new_compiles}
 out["speedup_cold_over_warm"] = h.latency_s / max(h2.latency_s, 1e-9)
 
 # THROUGHPUT vs queue depth, warm cache: depth jobs submitted together
-out["throughput"] = {{}}
+out["throughput"] = {}
 for depth in depths:
     t0 = time.perf_counter()
     handles = [svc.submit_kmeans(points(n_items, seed=10 + i), 4,
@@ -95,34 +88,15 @@ for depth in depths:
         hh.result(1800)
     dt = time.perf_counter() - t0
     assert all(hh.runner_misses == 0 for hh in handles)
-    out["throughput"][str(depth)] = {{"jobs": depth, "seconds": dt,
-                                     "jobs_per_s": depth / dt}}
+    out["throughput"][str(depth)] = {"jobs": depth, "seconds": dt,
+                                     "jobs_per_s": depth / dt}
 
 out["cache"] = svc.cache.stats()
 stats = svc.stats()
 out["jobs_completed"] = stats["jobs_completed"]
 out["round_base"] = stats["round_base"]
 svc.close()
-print("{marker}")
-print(json.dumps(out))
 """
-
-
-def _run_child(n_dev: int, n_items: int, depths, max_rounds: int,
-               max_chunk: int) -> dict:
-    code = _SERVICE_CHILD.format(n_dev=n_dev, n_items=n_items,
-                                 depths=list(depths), max_rounds=max_rounds,
-                                 max_chunk=max_chunk, marker=_MARKER)
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") +
-                        f" --xla_force_host_platform_device_count={n_dev}")
-    proc = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
-                          capture_output=True, text=True, env=env,
-                          timeout=3600)
-    if proc.returncode != 0:
-        raise RuntimeError(f"service bench child failed:\n{proc.stderr[-4000:]}")
-    payload = proc.stdout.split(_MARKER, 1)[1]
-    return json.loads(payload)
 
 
 def _run_sim(smoke: bool) -> dict:
@@ -153,7 +127,10 @@ def run(smoke: bool = False):
     max_rounds = 6 if smoke else 16
     max_chunk = 2 if smoke else 4
 
-    metrics = _run_child(n_dev, n_items, depths, max_rounds, max_chunk)
+    metrics = run_section(_SERVICE_SECTION,
+                          dict(n_items=n_items, depths=list(depths),
+                               max_rounds=max_rounds, max_chunk=max_chunk),
+                          n_dev=n_dev, timeout=3600)
     metrics["sim"] = _run_sim(smoke)
     LAST_METRICS.clear()
     LAST_METRICS.update(metrics)

@@ -15,17 +15,17 @@ layouts (`core/shuffle.py`):
     breakdown included so zero CTR expansion stays auditable leaf by leaf;
   * steady-state per-round time — an isolated secure shuffle (encrypt →
     all_to_all → decrypt under shard_map) timed for coalesced vs per-leaf
-    × keystream impls (pallas-interpret / jnp) on an 8-forced-host-device
-    mesh in a SUBPROCESS (device-count forcing must precede jax init —
-    same pattern as tests/conftest.run_in_subprocess). The 8-way mesh is
-    the honest harness: the shuffle is a COLLECTIVE path, and on a 1-device
+    × keystream impls (pallas — compiled on the TPU, interpreted on the
+    CPU — / jnp) on a multi-device mesh (`benchmarks/multidevice.py`: every
+    attached chip in this process, or 8 forced host devices in a CPU
+    child). A multi-device mesh is the honest harness: the shuffle is a COLLECTIVE path, and on a 1-device
     in-process mesh the timing measures XLA's thread-pool parallelism
     across per-leaf fusions instead of the wire (the per-leaf path's 3
     independent keystreams fan out over idle cores there, an artifact no
     real mesh reproduces — every device is busy with its own shard).
     Coalesced must not be slower than per-leaf (asserted, min-of-reps;
-    measured ~1.7x faster on pallas-interpret and ~4x on jnp, with ~3x
-    faster secure compiles).
+    measured on the CPU ~1.7x faster on the interpreted kernel and ~4x on
+    jnp, with ~3x faster secure compiles).
 
 Machine-readable output: `run()` fills the module-level `LAST_METRICS`
 dict, which `benchmarks/run.py` serializes to BENCH_shuffle.json (uploaded
@@ -34,15 +34,10 @@ by the CI bench-smoke lane alongside BENCH_driver.json).
 
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
-import textwrap
-
 import jax
 import jax.numpy as jnp
 
+from benchmarks.multidevice import run_section
 from repro.compat import make_mesh
 from repro.core.driver import make_iterative_runner
 from repro.core.kmeans import generate_points, make_kmeans_iterative_spec
@@ -53,45 +48,43 @@ from repro.tools.jaxprs import count_primitives
 # Filled by run(); serialized by benchmarks/run.py into BENCH_shuffle.json.
 LAST_METRICS: dict = {}
 
-IMPLS = ("pallas-interpret", "jnp")
+IMPLS = ("pallas", "jnp")
 
-_TIMING_CHILD = """
-import json, time
+_TIMING_SECTION = """
+import time
 import numpy as np
 import jax, jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from repro import compat
 from repro.compat import make_mesh
 from repro.core.shuffle import SecureShuffleConfig, keyed_all_to_all
 from repro.crypto import chacha
 
-n_dev, c, d, reps, impls = {n_dev}, {c}, {d}, {reps}, {impls}
 mesh = make_mesh((n_dev,), ("data",))
 rng = np.random.default_rng(0)
-tree = {{"k": jnp.asarray(rng.integers(0, 100, (n_dev * n_dev, c)), jnp.int32),
-        "v": {{"s": jnp.asarray(rng.normal(size=(n_dev * n_dev, c, d)).astype(np.float32)),
-              "c": jnp.asarray(rng.normal(size=(n_dev * n_dev, c)).astype(np.float32))}}}}
-specs = compat.tree_map(lambda _: P("data"), tree)
-out = {{}}
+tree = {"k": jnp.asarray(rng.integers(0, 100, (n_dev * n_dev, c)), jnp.int32),
+        "v": {"s": jnp.asarray(rng.normal(size=(n_dev * n_dev, c, d)).astype(np.float32)),
+              "c": jnp.asarray(rng.normal(size=(n_dev * n_dev, c)).astype(np.float32))}}
+specs = jax.tree.map(lambda _: P("data"), tree)
+out = {}
 for impl in impls:
-    out[impl] = {{}}
-    fns = {{}}
+    out[impl] = {}
+    fns = {}
     for coalesce, label in ((True, "coalesced"), (False, "per_leaf")):
         sec = SecureShuffleConfig(key_words=chacha.key_to_words(bytes(range(32))),
                                   nonce_words=chacha.nonce_to_words(b"\\x06" * 12),
                                   impl=impl, coalesce=coalesce)
         body = lambda t, sec=sec: keyed_all_to_all(t, "data", sec,
                                                    round_index=jnp.uint32(3))
-        fn = jax.jit(compat.shard_map(body, mesh=mesh, in_specs=(specs,),
-                                      out_specs=specs, check_vma=False))
+        fn = jax.jit(jax.shard_map(body, mesh=mesh, in_specs=(specs,),
+                                   out_specs=specs, check_vma=False))
         t0 = time.perf_counter()
         jax.block_until_ready(fn(tree))
-        out[impl][label] = {{"compile_s": time.perf_counter() - t0}}
+        out[impl][label] = {"compile_s": time.perf_counter() - t0}
         fns[label] = fn
     # INTERLEAVED trials: time both layouts back-to-back under the same
     # machine conditions (sequential phases drift by +-60% on shared CI
     # boxes and would swamp the ~1.3x layout difference), min over all
-    best = {{label: float("inf") for label in fns}}
+    best = {label: float("inf") for label in fns}
     for _ in range(3):
         for label, fn in fns.items():
             for _ in range(reps):
@@ -100,7 +93,6 @@ for impl in impls:
                 best[label] = min(best[label], time.perf_counter() - t0)
     for label in fns:
         out[impl][label]["us_per_round"] = best[label] * 1e6
-print(json.dumps(out))
 """
 
 
@@ -110,21 +102,6 @@ def _cfg(impl: str, coalesce) -> SecureShuffleConfig:
         nonce_words=chacha.nonce_to_words(b"\x06" * 12),
         impl=impl, coalesce=coalesce,
     )
-
-
-def _timing_subprocess(n_dev: int, c: int, d: int, reps: int, timeout: int) -> dict:
-    """Run the timing section on `n_dev` forced host devices (fresh jax)."""
-    code = textwrap.dedent(_TIMING_CHILD).format(
-        n_dev=n_dev, c=c, d=d, reps=reps, impls=repr(tuple(IMPLS)))
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_dev}"
-    env["PYTHONPATH"] = os.path.join(root, "src") + (
-        os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else "")
-    p = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                       text=True, timeout=timeout, env=env)
-    assert p.returncode == 0, f"timing child failed:\n{p.stderr[-3000:]}"
-    return json.loads(p.stdout.strip().splitlines()[-1])
 
 
 def run(smoke: bool = False):
@@ -144,7 +121,7 @@ def run(smoke: bool = False):
     c0 = jnp.asarray(pts[:k])
     for coalesce, label in ((True, "coalesced"), (False, "per_leaf")):
         runner = make_iterative_runner(spec, mesh,
-                                       secure=_cfg("pallas-interpret", coalesce))
+                                       secure=_cfg("pallas", coalesce))
         with record_wire_bytes() as recs:
             jaxpr = jax.make_jaxpr(runner.abstract_fn)(inputs, c0, jnp.uint32(0))
         a2a = count_primitives(jaxpr, "all_to_all")
@@ -173,13 +150,16 @@ def run(smoke: bool = False):
     assert co["per_leaf_bytes"] == pl["per_leaf_bytes"]
     assert co["bytes_per_round"] == pl["bytes_per_round"]
 
-    # --- steady-state per-round time: isolated secure shuffle, 8-dev mesh ----
-    # The same 3-leaf tree shape on 8 forced host devices in a subprocess
-    # (module docstring: why 1-device in-process timing would be a lie).
+    # --- steady-state per-round time: isolated secure shuffle, multi-device --
+    # The same 3-leaf tree shape over several devices (module docstring: why
+    # 1-device in-process timing on the CPU would be a lie).
     n_dev = 8
     c, d = (64, 4) if smoke else (128, 8)
     reps = 5 if smoke else 10
-    timing = _timing_subprocess(n_dev, c, d, reps, timeout=1800)
+    timing = run_section(_TIMING_SECTION,
+                         dict(c=c, d=d, reps=reps, impls=IMPLS),
+                         n_dev=n_dev, timeout=1800)
+    n_dev = timing["devices"]["count"]
     metrics["micro_shuffle"] = {"n_dev": n_dev, "c": c, "d": d, "reps": reps,
                                 **timing}
     for impl in IMPLS:

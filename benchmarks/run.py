@@ -76,6 +76,7 @@ from benchmarks import (
     bench_shuffle,
     bench_tcb,
 )
+from repro.compile_cache import enable_compile_cache
 
 MODULES = [
     bench_tcb,
@@ -145,6 +146,7 @@ def main(argv=None) -> None:
                     help="path for the calibrated cost-model prediction-error "
                          "metrics (schema in benchmarks/README.md)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     modules = SMOKE_MODULES if args.smoke else MODULES
     print("name,us_per_call,derived")

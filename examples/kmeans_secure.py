@@ -12,6 +12,7 @@ import numpy as np
 import jax
 
 from repro.compat import make_mesh
+from repro.compile_cache import enable_compile_cache
 from repro.core.kmeans import generate_points, kmeans_fit
 from repro.core.paging import SecurePager
 from repro.core.shuffle import SecureShuffleConfig
@@ -22,6 +23,7 @@ from repro.runtime.sim import TimingModel
 
 
 def main():
+    enable_compile_cache()
     mesh = make_mesh((1,), ("data",))
     pts, true_centers = generate_points(20000, 10, seed=0, spread=0.05)
 

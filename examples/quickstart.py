@@ -14,6 +14,7 @@ import numpy as np
 import jax
 
 from repro.compat import make_mesh
+from repro.compile_cache import enable_compile_cache
 from repro.core.shuffle import SecureShuffleConfig
 from repro.core.wordcount import wordcount
 from repro.crypto import chacha
@@ -27,6 +28,7 @@ LINES = [
 
 
 def main():
+    enable_compile_cache()
     print("=== cluster level (pub/sub protocol, simulated nodes) ===")
     print(f"user map script:\n{WORDCOUNT_MAP}")
     cluster, client, _ = make_cluster(8)

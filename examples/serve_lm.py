@@ -11,12 +11,14 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCH_IDS, get_config
 from repro.models.lm import init_params
 from repro.serve.engine import decode_step, init_cache, prefill
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="zamba2-1.2b", choices=ARCH_IDS)
     ap.add_argument("--batch", type=int, default=4)

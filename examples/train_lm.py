@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from repro.compat import make_mesh
 from repro.checkpoint.manager import CheckpointManager
+from repro.compile_cache import enable_compile_cache
 from repro.configs import ARCH_IDS, get_config
 from repro.crypto.keys import make_session_keys
 from repro.data.pipeline import SecureShardedSource
@@ -28,6 +29,7 @@ from repro.train.step import SecureIngest, make_train_step
 
 
 def main():
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="rwkv6-1.6b", choices=ARCH_IDS)
     ap.add_argument("--steps", type=int, default=200)

@@ -27,7 +27,6 @@ import numpy as np
 
 import jax
 
-from repro import compat
 from repro.crypto.mac import mac_keys_from_keystream, mac_tag_host, mac_verify_host
 
 
@@ -70,7 +69,7 @@ class CheckpointManager:
         final = os.path.join(self.dir, f"step_{step:08d}")
         shutil.rmtree(tmp, ignore_errors=True)
         os.makedirs(tmp)
-        flat = _flatten(compat.tree_map(lambda x: np.asarray(x), tree))
+        flat = _flatten(jax.tree.map(lambda x: np.asarray(x), tree))
         manifest = {"step": step, "leaves": {}, "extra": extra or {}}
         for path, arr in flat.items():
             fname = path.replace("/", "__") + ".npy"

@@ -1,137 +1,23 @@
-"""jax version-compatibility layer.
+"""The mesh constructor shared by every mesh-building caller.
 
-The repo targets both jax 0.4.x (the pinned toolchain on this machine) and
-newer releases whose public API moved under different names:
-
-  * ``jax.shard_map``           — 0.4.x only has ``jax.experimental.shard_map``
-                                  whose replication-check kwarg is ``check_rep``
-                                  (renamed ``check_vma`` upstream);
-  * ``jax.sharding.AxisType``   — absent on 0.4.x (meshes are implicitly Auto);
-  * ``jax.make_mesh(axis_types=...)`` — the kwarg does not exist on 0.4.x;
-  * ``jax.tree.*``              — present on 0.4.x but kept behind one alias so
-                                  very old/new trees of utilities stay swappable.
-
-Everything that builds meshes or shard_map programs (core engine, iterative
-driver, MoE dispatch, checkpointing, sharding rules, tests, examples,
-benchmarks) imports from here instead of touching the moving jax surface
-directly.
+`make_mesh` pins every axis to `AxisType.Auto`. `jax.make_mesh` defaults
+to Explicit axes, under which `with_sharding_constraint` refuses named
+specs and array avals carry their sharding (so a runner's output state
+no longer matches the aval it was traced with). The programs here are
+written for the Auto (compiler-propagated) sharding model, so they say so.
 """
 
 from __future__ import annotations
 
-import enum
-import inspect
-import math
-from typing import Any, Callable, Sequence
-
-import numpy as np
+from typing import Sequence
 
 import jax
-from jax.sharding import Mesh
-
-# ---------------------------------------------------------------------------
-# tree utilities
-# ---------------------------------------------------------------------------
-
-if hasattr(jax, "tree"):
-    tree_map = jax.tree.map
-    tree_leaves = jax.tree.leaves
-    tree_flatten = jax.tree.flatten
-    tree_unflatten = jax.tree.unflatten
-    tree_structure = jax.tree.structure
-else:  # pragma: no cover - ancient jax
-    from jax import tree_util as _tu
-
-    tree_map = _tu.tree_map
-    tree_leaves = _tu.tree_leaves
-    tree_flatten = _tu.tree_flatten
-    tree_unflatten = _tu.tree_unflatten
-    tree_structure = _tu.tree_structure
+from jax.sharding import AxisType, Mesh
 
 
-# ---------------------------------------------------------------------------
-# AxisType
-# ---------------------------------------------------------------------------
-
-try:
-    AxisType = jax.sharding.AxisType  # jax >= 0.5-ish
-except AttributeError:
-
-    class AxisType(enum.Enum):
-        """Stand-in for ``jax.sharding.AxisType`` on jax 0.4.x.
-
-        0.4.x meshes behave as all-Auto, so the value is accepted (and
-        dropped) by :func:`make_mesh` purely for source compatibility.
-        """
-
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
-
-
-# ---------------------------------------------------------------------------
-# make_mesh
-# ---------------------------------------------------------------------------
-
-_MAKE_MESH_PARAMS: frozenset[str] = (
-    frozenset(inspect.signature(jax.make_mesh).parameters)
-    if hasattr(jax, "make_mesh")
-    else frozenset()
-)
-
-
-def make_mesh(
-    axis_shapes: Sequence[int],
-    axis_names: Sequence[str],
-    *,
-    devices=None,
-    axis_types=None,
-) -> Mesh:
-    """Version-safe ``jax.make_mesh``: ``axis_types`` is forwarded when the
-    running jax understands it and silently dropped otherwise (0.4.x meshes
-    are implicitly Auto, which is what every caller here wants)."""
+def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
+              devices=None) -> Mesh:
+    """`jax.make_mesh` with every axis Auto (module docstring)."""
     axis_shapes = tuple(axis_shapes)
-    axis_names = tuple(axis_names)
-    if hasattr(jax, "make_mesh"):
-        kwargs: dict[str, Any] = {}
-        if devices is not None:
-            kwargs["devices"] = devices
-        if axis_types is not None and "axis_types" in _MAKE_MESH_PARAMS:
-            kwargs["axis_types"] = axis_types
-        return jax.make_mesh(axis_shapes, axis_names, **kwargs)
-    # pragma: no cover - pre-make_mesh jax
-    n = math.prod(axis_shapes)
-    devices = list(devices) if devices is not None else jax.devices()[:n]
-    return Mesh(np.array(devices).reshape(axis_shapes), axis_names)
-
-
-# ---------------------------------------------------------------------------
-# shard_map
-# ---------------------------------------------------------------------------
-
-if hasattr(jax, "shard_map"):
-    _shard_map_impl = jax.shard_map
-    _CHECK_KW = (
-        "check_vma"
-        if "check_vma" in inspect.signature(jax.shard_map).parameters
-        else "check_rep"
-    )
-else:
-    from jax.experimental.shard_map import shard_map as _shard_map_impl
-
-    _CHECK_KW = "check_rep"
-
-
-def shard_map(f: Callable, mesh: Mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-safe shard_map.
-
-    ``check_vma`` maps onto the running jax's replication-check kwarg
-    (``check_vma`` on new jax, ``check_rep`` on 0.4.x experimental).
-    """
-    return _shard_map_impl(
-        f,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        **{_CHECK_KW: check_vma},
-    )
+    return jax.make_mesh(axis_shapes, tuple(axis_names), devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axis_shapes))

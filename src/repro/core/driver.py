@@ -225,9 +225,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
 from repro.core.engine import default_hash
 from repro.core.shuffle import (
     SecureShuffleConfig,
@@ -441,7 +440,7 @@ class _ShardedHaltGuard:
 
 
 for _name in (
-    "__jax_array__", "__array__", "__bool__", "__int__", "__float__",
+    "__array__", "__bool__", "__int__", "__float__",
     "__index__", "__len__", "__iter__", "__getitem__", "__neg__", "__pos__",
     "__abs__", "__invert__", "__add__", "__radd__", "__sub__", "__rsub__",
     "__mul__", "__rmul__", "__truediv__", "__rtruediv__", "__floordiv__",
@@ -535,7 +534,7 @@ def _round_body(state, r, *, inputs, spec: IterativeSpec, axis_name: str, n_shar
     recv = keyed_all_to_all({"k": bk, "v": bv}, axis_name, secure, round_index=r,
                             coalesce=coalesce)
     flat_k = recv["k"].reshape(-1)
-    flat_v = compat.tree_map(lambda x: x.reshape((-1,) + x.shape[2:]), recv["v"])
+    flat_v = jax.tree.map(lambda x: x.reshape((-1,) + x.shape[2:]), recv["v"])
     valid = flat_k >= 0
 
     new_state, aux = spec.reduce_fn(state, flat_k, flat_v, valid, r)
@@ -691,13 +690,13 @@ def make_iterative_runner(
         extra_out = ()
 
     def in_specs(inputs_tree):
-        return compat.tree_map(lambda _: P(axis_name), inputs_tree)
+        return jax.tree.map(lambda _: P(axis_name), inputs_tree)
 
     def run(inputs, state, round_offset=0):
         # per-leaf carried-state layout (module docstring): identical spec
         # tree in and out — the driver never reshards between rounds
         state_spec_tree, _ = _resolve_state_specs(spec, state)
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(in_specs(inputs), state_spec_tree, P()),
@@ -938,12 +937,25 @@ def run_until_chunks(
     # duck-typed cache: the serving RunnerCache view, or the legacy dict
     get_or_build = getattr(runners, "get_or_build", None)
 
-    state = init_state
+    # place inputs and carried state ONCE in the layouts the runner's
+    # shard_map declares: inputs split over the axis (not parked on the
+    # default device and re-split every chunk), state in its per-leaf specs
+    # — the sharding every chunk returns it in, so chunk 2 sees the aval
+    # chunk 1 was traced with and reuses its program
+    inputs = jax.device_put(inputs, NamedSharding(mesh, P(axis_name)))
+    state_spec_tree, _ = _resolve_state_specs(spec, init_state)
+    state = jax.device_put(
+        init_state,
+        jax.tree.map(lambda p: NamedSharding(mesh, p), state_spec_tree,
+                     is_leaf=lambda x: isinstance(x, P)))
     if donate_state:
-        # one up-front copy shields the caller's init_state buffers from the
-        # first chunk's donation; all later chunks donate run_until's own
-        # output state, which nothing else holds
-        state = jax.tree.map(lambda x: jnp.array(x, copy=True), init_state)
+        # placement may hand back the caller's own device buffer (even when
+        # it reshards), so copy every leaf that came in as a device array:
+        # the first chunk's donation must not delete init_state. All later
+        # chunks donate run_until's own output state, which nothing else holds
+        state = jax.tree.map(
+            lambda src, x: x.copy() if isinstance(src, jax.Array) else x,
+            init_state, state)
     executed = dispatched = n_dispatches = 0
     halted = False
     aux_chunks: list = []

@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.core.shuffle import SecureShuffleConfig, bucket_pack, keyed_all_to_all
 
 
@@ -104,8 +103,8 @@ def run_mapreduce(
         secure = secure.with_impl(chacha_impl).with_coalesce(coalesce)
     n_shards = mesh.shape[axis_name]
     body = partial(_shard_body, spec=spec, axis_name=axis_name, n_shards=n_shards, secure=secure)
-    in_specs = (P(axis_name), compat.tree_map(lambda _: P(axis_name), values))
-    fn = compat.shard_map(
+    in_specs = (P(axis_name), jax.tree.map(lambda _: P(axis_name), values))
+    fn = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=(out_specs, P()), check_vma=False
     )
     return jax.jit(fn)(keys, values)

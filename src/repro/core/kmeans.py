@@ -37,7 +37,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro import compat
 from repro.core.driver import IterativeSpec, run_until
 from repro.core.engine import MapReduceSpec, identity_hash
 from repro.core.shuffle import SecureShuffleConfig, bucket_pack, keyed_all_to_all
@@ -95,7 +94,7 @@ def _kmeans_shard_step(points, weights, centers, *, axis_name, n_shards, secure,
     recv = keyed_all_to_all({"k": bk, "v": bv}, axis_name, secure)
 
     rk = recv["k"].reshape(-1)
-    rv = compat.tree_map(lambda x: x.reshape((-1,) + x.shape[2:]), recv["v"])
+    rv = jax.tree.map(lambda x: x.reshape((-1,) + x.shape[2:]), recv["v"])
     return _reduce_centers(centers, rk, rv, rk >= 0, axis_name=axis_name, n_shards=n_shards)
 
 
@@ -117,7 +116,7 @@ def make_kmeans_step(mesh: Mesh, axis_name: str = "data", secure: SecureShuffleC
         secure=secure,
         impl=impl,
     )
-    fn = compat.shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis_name), P(axis_name), P()),

@@ -94,9 +94,9 @@ Selection: `SecureShuffleConfig.impl` ('auto' | 'pallas' |
 'pallas-interpret' | 'jnp'). 'auto' resolves to the `REPRO_CHACHA_IMPL`
 environment variable when set, else 'pallas'; an explicit non-'auto' value
 always wins over the environment. The choice is read at trace time — an env
-flip after a runner is jitted does not retrace it. If the Pallas frontend is
-unimportable on this platform, 'auto'/'pallas' silently fall back to 'jnp'
-(same bits, slower compile).
+flip after a runner is jitted does not retrace it. 'pallas' runs the kernel
+compiled on the TPU and interpreted on any other backend
+(`repro.kernels.resolve_interpret`).
 """
 
 from __future__ import annotations
@@ -113,20 +113,13 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.crypto import ctr as _ctr
-from repro.crypto.chacha import chacha20_block_words, chacha20_keystream_words
 from repro.crypto.ctr import words_for
-
-try:  # the Pallas frontend may be absent on exotic platforms
-    from repro.kernels.chacha20.ops import (
-        chacha20_xor_rows,
-        chacha20_xor_rows_coalesced,
-        make_state0,
-    )
-
-    _HAVE_PALLAS = True
-except ImportError:  # pragma: no cover - exercised only without Pallas
-    chacha20_xor_rows = chacha20_xor_rows_coalesced = make_state0 = None
-    _HAVE_PALLAS = False
+from repro.kernels import resolve_interpret
+from repro.kernels.chacha20.ops import (
+    chacha20_xor_rows,
+    chacha20_xor_rows_coalesced,
+    make_state0,
+)
 
 CHACHA_IMPL_ENV = "REPRO_CHACHA_IMPL"
 _VALID_IMPLS = ("auto", "pallas", "pallas-interpret", "jnp")
@@ -187,7 +180,7 @@ def resolve_chacha_impl(impl: str = "auto") -> tuple[str, bool]:
     `repro/perf/model.py`), then to the measured default 'pallas'; explicit
     values win over the environment. 'pallas-interpret' forces interpret
     mode even on a backend with a compiled Pallas lowering; plain 'pallas'
-    interprets only off-TPU. Falls back to 'jnp' when Pallas is unimportable.
+    interprets only off-TPU (`repro.kernels.resolve_interpret`).
     """
     from_env = False
     if impl in (None, "auto"):
@@ -205,11 +198,11 @@ def resolve_chacha_impl(impl: str = "auto") -> tuple[str, bool]:
                 f"(unset ${CHACHA_IMPL_ENV} to use the default 'pallas')")
         raise ValueError(
             f"chacha impl must be one of {_VALID_IMPLS[1:]}, got {impl!r}")
-    if impl == "jnp" or not _HAVE_PALLAS:
+    if impl == "jnp":
         return "jnp", True
     if impl == "pallas-interpret":
         return "pallas", True
-    return "pallas", jax.default_backend() != "tpu"
+    return "pallas", resolve_interpret(None)
 
 
 @dataclass(frozen=True)
@@ -319,26 +312,13 @@ def _crypt_rows(cfg: SecureShuffleConfig, words, nonce_ids, ctr_starts, round_id
 
     Row i uses nonce word 0 XOR nonce_ids[i] and absolute block counter start
     ctr_starts[i]; nonce word 1 carries the round index. Dispatches to the
-    backend selected by `cfg.impl` via `repro.kernels.chacha20`; when the
-    Pallas frontend is unimportable, a local vmapped jnp path (bit-identical
-    by construction) keeps secure mode working.
+    backend selected by `cfg.impl` via `repro.kernels.chacha20`.
     """
-    nonce_ids = jnp.asarray(nonce_ids, jnp.uint32)
-    ctr_starts = jnp.asarray(ctr_starts, jnp.uint32)
-    base_nonce = _round_nonce(cfg, round_id)
-    if _HAVE_PALLAS:
-        impl, interpret = resolve_chacha_impl(cfg.impl)
-        state0 = make_state0(cfg.key_words, base_nonce, 0)
-        return chacha20_xor_rows(words, state0, nonce_ids, ctr_starts,
-                                 impl=impl, interpret=interpret)
-
-    n_words = words.shape[1]  # pragma: no cover - exercised only without Pallas
-
-    def one(row, nid, ctr0):
-        nonce = base_nonce.at[0].set(base_nonce[0] ^ nid)
-        return row ^ chacha20_keystream_words(cfg.key_words, nonce, ctr0, n_words)
-
-    return jax.vmap(one)(words, nonce_ids, ctr_starts)
+    impl, interpret = resolve_chacha_impl(cfg.impl)
+    state0 = make_state0(cfg.key_words, _round_nonce(cfg, round_id), 0)
+    return chacha20_xor_rows(words, state0, jnp.asarray(nonce_ids, jnp.uint32),
+                             jnp.asarray(ctr_starts, jnp.uint32),
+                             impl=impl, interpret=interpret)
 
 
 def _keystream_rows(cfg: SecureShuffleConfig, nonce_ids, ctr_rows, offset, blocks, n_words,
@@ -508,23 +488,12 @@ def _crypt_wire_coalesced(wire, layout: _WireLayout, cfg, nonce_ids, ctr_rows,
     ctr_rows = jnp.asarray(ctr_rows, jnp.uint32)
     ctr_base = jnp.uint32(cfg.counter0) + jnp.asarray(layout.ctr_base, jnp.uint32)
     ctr_rowmul = jnp.asarray(layout.ctr_rowmul, jnp.uint32)
-    base_nonce = _round_nonce(cfg, round_id)
     zeros = jnp.zeros((wire.shape[0], layout.total_words), jnp.uint32)
-    if _HAVE_PALLAS:
-        impl, interpret = resolve_chacha_impl(cfg.impl)
-        state0 = make_state0(cfg.key_words, base_nonce, 0)
-        ks = chacha20_xor_rows_coalesced(zeros, state0, nonce_ids, ctr_rows,
-                                         ctr_base, ctr_rowmul,
-                                         impl=impl, interpret=interpret)
-    else:  # pragma: no cover - exercised only without Pallas
-        key_words = jnp.asarray(cfg.key_words, jnp.uint32)
-
-        def one(nid, rc):
-            nonce = base_nonce.at[0].set(base_nonce[0] ^ nid)
-            counters = ctr_base + ctr_rowmul * rc
-            return chacha20_block_words(key_words, counters, nonce).reshape(-1)
-
-        ks = jax.vmap(one)(nonce_ids, ctr_rows)
+    impl, interpret = resolve_chacha_impl(cfg.impl)
+    state0 = make_state0(cfg.key_words, _round_nonce(cfg, round_id), 0)
+    ks = chacha20_xor_rows_coalesced(zeros, state0, nonce_ids, ctr_rows,
+                                     ctr_base, ctr_rowmul,
+                                     impl=impl, interpret=interpret)
     return wire ^ _packed_keystream(ks, layout)
 
 

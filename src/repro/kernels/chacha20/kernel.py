@@ -37,8 +37,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
-from repro.crypto.chacha import CONSTANT_WORDS, _QR_SCHEDULE
+from repro.crypto.chacha import _QR_SCHEDULE
+from repro.kernels import resolve_interpret
 
 DEFAULT_BLOCK_ROWS = 2048
 # blocks per lane tile of the (16, n_blocks) layout; multiple of the 128-lane
@@ -46,15 +48,13 @@ DEFAULT_BLOCK_ROWS = 2048
 DEFAULT_BLOCK_LANES = 2048
 
 
-def _keystream_tile(init, axis: int = 1):
+def _keystream_tile(init):
     """20 unrolled ARX rounds + feed-forward over 16 state vectors.
 
-    The shared cryptographic core of every tile kernel: any change here
-    applies to the single-stream, the batched block-row, and the block-lane
-    kernels alike, so their keystreams cannot diverge. `init` is 16 arrays
-    of identical shape; the result concatenates the 16 output words along
-    `axis` — axis=1 with (B, 1) vectors yields the (B, 16) block-row tile,
-    axis=0 with (1, L) vectors yields the (16, L) block-lane tile.
+    The one cryptographic core of every entry point (they all lower onto
+    the block-lane kernel), so their keystreams cannot diverge. `init` is 16
+    (1, L) vectors — one per state word, blocks on the lanes; returns the 16
+    keystream words as (1, L) vectors.
     """
 
     def rotl(v, n):
@@ -74,7 +74,7 @@ def _keystream_tile(init, axis: int = 1):
             xb = rotl(xb ^ xc, 7)
             xs[a], xs[b], xs[c], xs[d] = xa, xb, xc, xd
 
-    return jnp.concatenate([x + x0 for x, x0 in zip(xs, init)], axis=axis)
+    return [x + x0 for x, x0 in zip(xs, init)]
 
 
 def chacha20_xor_blocks(
@@ -82,7 +82,7 @@ def chacha20_xor_blocks(
     state0: jax.Array,
     *,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """XOR a (n_blocks, 16) u32 message with the keystream.
 
@@ -121,30 +121,26 @@ def _chacha20_lanes_tile_kernel(state0_ref, nid_ref, row_ref, base_ref,
     The grid is (n_rows, n_blocks // block_lanes): program (i, j) encrypts
     lane-layout blocks [j*L, (j+1)*L) of wire row i, where the data tile is
     (1, 16, L) — word index on the sublane dim, blocks on the lane dim. The
-    row's nonce is the template nonce with word 0 XOR nid_ref[0]; the block
-    counter of lane l is `base_ref[l] + mul_ref[l] * row_ref[0]` (absolute —
-    state0 word 12 is ignored), so one launch covers blocks whose counters
-    advance with different per-segment strides (the coalesced wire). The
-    ARX core is the shared `_keystream_tile`, concatenated on the sublane
-    axis so each of the 16 output words is a full (1, L) lane vector.
+    scalars (template state, per-row nonce id and counter operand) are read
+    from SMEM; the row's nonce is the template nonce with word 0 XOR
+    nid_ref[i], and the block counter of lane l is
+    `base_ref[0, l] + mul_ref[0, l] * row_ref[i]` (absolute — state0 word 12
+    is ignored), so one launch covers blocks whose counters advance with
+    different per-segment strides (the coalesced wire). Each of the 16
+    keystream words is a full (1, L) lane vector; they stack on the sublane
+    axis into the (16, L) tile, which is stored once (sixteen separate row
+    stores made the interpreted CPU program take minutes to compile).
     """
-    s0 = state0_ref[...]  # (16,) u32 template: const | key | (ignored) | nonce
-    nid = nid_ref[0]
-    row_ctr = row_ref[0]
+    i = pl.program_id(0)
+    ctr = base_ref[...] + mul_ref[...] * row_ref[i]  # (1, L)
 
-    ctr = (base_ref[...] + mul_ref[...] * row_ctr)[None, :]  # (1, L)
-    nonce0 = s0[13] ^ nid
+    def splat(word):
+        return jnp.full((1, block_lanes), word, jnp.uint32)
 
-    init = []
-    for i in range(16):
-        if i == 12:
-            init.append(ctr)
-        elif i == 13:
-            init.append(jnp.broadcast_to(nonce0, (1, block_lanes)))
-        else:
-            init.append(jnp.broadcast_to(s0[i], (1, block_lanes)))
-
-    y_ref[...] = x_ref[...] ^ _keystream_tile(init, axis=0)[None]
+    init = [splat(state0_ref[w]) for w in range(12)]
+    init += [ctr, splat(state0_ref[13] ^ nid_ref[i]),
+             splat(state0_ref[14]), splat(state0_ref[15])]
+    y_ref[0] = x_ref[0] ^ jnp.concatenate(_keystream_tile(init), axis=0)
 
 
 def chacha20_xor_row_lanes(
@@ -156,7 +152,7 @@ def chacha20_xor_row_lanes(
     ctr_rowmul: jax.Array,
     *,
     block_lanes: int = DEFAULT_BLOCK_LANES,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """XOR an (n_rows, 16, n_blocks) u32 lane-layout buffer with keystream.
 
@@ -169,7 +165,12 @@ def chacha20_xor_row_lanes(
     leaf's counter offset + intra-leaf block index and ctr_rowmul the leaf's
     blocks-per-row stride. n_blocks must be a multiple of block_lanes
     (ops.py pads); the legacy contiguous layout is base=iota, rowmul=1,
-    ctr_rows=per-row starts.
+    ctr_rows=per-row starts. `interpret` None follows the backend
+    (`repro.kernels.resolve_interpret`).
+
+    state0, nonce_ids and ctr_rows sit whole in SMEM (they are read as
+    scalars); the per-block counter vectors travel as lane-dense (1, L)
+    blocks of a (1, n_blocks) view.
     """
     n_rows, w, n_blocks = x_lanes.shape
     assert w == 16 and x_lanes.dtype == jnp.uint32
@@ -177,21 +178,18 @@ def chacha20_xor_row_lanes(
     assert nonce_ids.shape == (n_rows,) and ctr_rows.shape == (n_rows,)
     assert ctr_base.shape == (n_blocks,) and ctr_rowmul.shape == (n_blocks,)
     grid = (n_rows, n_blocks // block_lanes)
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    lane_vec = pl.BlockSpec((1, block_lanes), lambda i, j: (0, j))
+    tile = pl.BlockSpec((1, 16, block_lanes), lambda i, j: (i, 0, j))
     return pl.pallas_call(
         functools.partial(_chacha20_lanes_tile_kernel, block_lanes=block_lanes),
         grid=grid,
-        in_specs=[
-            pl.BlockSpec((16,), lambda i, j: (0,)),  # template state, replicated
-            pl.BlockSpec((1,), lambda i, j: (i,)),   # per-row nonce XOR id
-            pl.BlockSpec((1,), lambda i, j: (i,)),   # per-row counter operand
-            pl.BlockSpec((block_lanes,), lambda i, j: (j,)),  # per-block base
-            pl.BlockSpec((block_lanes,), lambda i, j: (j,)),  # per-block stride
-            pl.BlockSpec((1, 16, block_lanes), lambda i, j: (i, 0, j)),
-        ],
-        out_specs=pl.BlockSpec((1, 16, block_lanes), lambda i, j: (i, 0, j)),
+        in_specs=[smem, smem, smem, lane_vec, lane_vec, tile],
+        out_specs=tile,
         out_shape=jax.ShapeDtypeStruct((n_rows, 16, n_blocks), jnp.uint32),
-        interpret=interpret,
-    )(state0, nonce_ids, ctr_rows, ctr_base, ctr_rowmul, x_lanes)
+        interpret=resolve_interpret(interpret),
+    )(state0, nonce_ids, ctr_rows, ctr_base.reshape(1, n_blocks),
+      ctr_rowmul.reshape(1, n_blocks), x_lanes)
 
 
 def chacha20_xor_row_blocks(
@@ -201,7 +199,7 @@ def chacha20_xor_row_blocks(
     ctr_starts: jax.Array,
     *,
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """XOR an (n_rows, n_blocks, 16) u32 buffer with per-row keystreams.
 

@@ -1,6 +1,8 @@
 """jit'd wrappers for the chacha20 kernel: padding, word packing, dispatch.
 
-`impl` selects: 'pallas' (interpret on CPU, compiled on TPU), 'jnp' (oracle).
+`impl` selects: 'pallas' (the kernel) or 'jnp' (oracle). `interpret=None`
+follows the backend (`repro.kernels.resolve_interpret`): compiled on the TPU,
+interpreted elsewhere.
 
 Since the lane re-tiling, every rows-style entry point lowers onto the
 (16, n_blocks) BLOCK-LANE kernel (`kernel.chacha20_xor_row_lanes`): the
@@ -19,7 +21,7 @@ import jax.numpy as jnp
 
 from repro.crypto import ctr as _ctr
 from repro.crypto.chacha import CONSTANT_WORDS, chacha20_block_words
-from repro.kernels.chacha20 import ref as _ref
+from repro.kernels import resolve_interpret
 from repro.kernels.chacha20.kernel import (
     DEFAULT_BLOCK_LANES,
     DEFAULT_BLOCK_ROWS,
@@ -78,7 +80,7 @@ def chacha20_xor_words(
     *,
     impl: str = "pallas",
     block_rows: int = DEFAULT_BLOCK_ROWS,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """XOR a flat (n,) u32 word stream with the keystream starting at state0.
 
@@ -89,6 +91,7 @@ def chacha20_xor_words(
     flat `ctr_crypt_array` path shares both the full-lane compiled lowering
     and the fast interpret shape with the wire hot path.
     """
+    interpret = resolve_interpret(interpret)
     n = words.shape[0]
     n_blocks = -(-n // 16)
     if impl == "jnp" or n_blocks == 0:
@@ -117,7 +120,7 @@ def chacha20_xor_rows(
     *,
     impl: str = "pallas",
     block_lanes: int = DEFAULT_BLOCK_LANES,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """XOR an (R, n_words) u32 wire buffer with per-row keystreams.
 
@@ -129,6 +132,7 @@ def chacha20_xor_rows(
     kernel: base=iota, rowmul=1); 'jnp' is the bit-exact vmapped oracle
     kept for differential testing.
     """
+    interpret = resolve_interpret(interpret)
     r, n = words.shape
     nonce_ids = jnp.asarray(nonce_ids, jnp.uint32)
     ctr_starts = jnp.asarray(ctr_starts, jnp.uint32)
@@ -162,7 +166,7 @@ def chacha20_xor_rows_coalesced(
     *,
     impl: str = "pallas",
     block_lanes: int = DEFAULT_BLOCK_LANES,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """XOR an (R, 16·n_blocks) u32 COALESCED wire with per-row keystreams.
 
@@ -179,6 +183,7 @@ def chacha20_xor_rows_coalesced(
     for differential testing. n_words must be a multiple of 16 (the wire is
     block-aligned by construction).
     """
+    interpret = resolve_interpret(interpret)
     r, n = words.shape
     if n % 16:
         raise ValueError(f"coalesced wire must be block-aligned, got n_words={n}")
@@ -209,7 +214,7 @@ def ctr_crypt_array(
     counter0=0,
     *,
     impl: str = "pallas",
-    interpret: bool = True,
+    interpret: bool | None = None,
 ) -> jax.Array:
     """Encrypt/decrypt an arbitrary-dtype array via the kernel (XOR stream)."""
     shape, dtype = x.shape, x.dtype

@@ -14,8 +14,12 @@ paging cliff). The fusion keeps everything for a tile in VMEM:
 
 Accumulator outputs map every grid step to the same block; TPU grid order is
 sequential so `+=` is well-defined (the standard Pallas reduction idiom).
-Tiling: TN=512 rows; centers (K, D) stay resident. VMEM ≈ TN·D + K·D + TN·K
-floats — e.g. D=64, K=256: ~0.9 MB.
+Tiling: TN=1024 rows; centers (K, D) stay resident. VMEM ≈ TN·D + K·D + TN·K
+floats — e.g. D=64, K=256: ~1.8 MB. The compiled TPU lowering needs TN to be
+a multiple of 1024: XLA lays a 1-D s32 array out in T(1024) tiles and Mosaic
+refuses an `assign` block whose tiling differs (ops.py pads N to the tile).
+The (N, D) and (N, 1) operands are padded to 128 lanes in HBM, so at D=2 the
+layout copies cost ~1 KiB per point (a known cost of this row layout).
 """
 
 from __future__ import annotations
@@ -26,7 +30,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-DEFAULT_TILE_N = 512
+from repro.kernels import resolve_interpret
+
+DEFAULT_TILE_N = 1024
+# full f32 matmuls: the TPU's default precision rounds f32 operands to bf16
+# (ref.py gives the measured cost)
+_F32 = jax.lax.Precision.HIGHEST
 
 
 def _kmeans_tile_kernel(x_ref, c_ref, w_ref, assign_ref, sums_ref, counts_ref):
@@ -39,7 +48,8 @@ def _kmeans_tile_kernel(x_ref, c_ref, w_ref, assign_ref, sums_ref, counts_ref):
     x2 = jnp.sum(x * x, axis=1, keepdims=True)  # (TN, 1)
     c2 = jnp.sum(c * c, axis=1)[None, :]  # (1, K)
     xc = jax.lax.dot_general(
-        x, c, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        x, c, (((1,), (1,)), ((), ())), precision=_F32,
+        preferred_element_type=jnp.float32,
     )  # (TN, K) = x @ c.T  on the MXU
     d2 = x2 + c2 - 2.0 * xc
 
@@ -52,7 +62,8 @@ def _kmeans_tile_kernel(x_ref, c_ref, w_ref, assign_ref, sums_ref, counts_ref):
     ) * w  # (TN, K), weighted
 
     part_sums = jax.lax.dot_general(
-        onehot, x, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        onehot, x, (((0,), (0,)), ((), ())), precision=_F32,
+        preferred_element_type=jnp.float32,
     )  # (K, D) = onehot.T @ x
     part_counts = jnp.sum(onehot, axis=0, keepdims=True)  # (1, K)
 
@@ -71,9 +82,10 @@ def kmeans_assign_tiles(
     weights: jax.Array,
     *,
     tile_n: int = DEFAULT_TILE_N,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Fused assign+accumulate. N must be a multiple of tile_n (ops.py pads).
+    `interpret` None follows the backend (`repro.kernels.resolve_interpret`).
 
     Returns assign (N,) int32, sums (K, D) f32, counts (1, K) f32.
     """
@@ -99,5 +111,5 @@ def kmeans_assign_tiles(
             jax.ShapeDtypeStruct((k, d), jnp.float32),
             jax.ShapeDtypeStruct((1, k), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(points, centers, weights.reshape(n, 1))

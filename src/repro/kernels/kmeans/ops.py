@@ -7,6 +7,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import resolve_interpret
 from repro.kernels.kmeans.kernel import DEFAULT_TILE_N, kmeans_assign_tiles
 from repro.kernels.kmeans.ref import kmeans_assign_ref
 
@@ -19,12 +20,15 @@ def kmeans_assign(
     *,
     impl: str = "pallas",
     tile_n: int = DEFAULT_TILE_N,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """assign (N,) int32, sums (K, D) f32, counts (K,) f32.
 
     Padded points get weight 0: they contribute to nothing (their assignment
-    entries are discarded by the caller via the original N).
+    entries are discarded by the caller via the original N). `interpret`
+    None follows the backend (`repro.kernels.resolve_interpret`). Interpreted,
+    a small N shrinks the tile to the next power of two; compiled, the tile
+    stays `tile_n` (the TPU layout rule in `kernel.py`) and N pads up to it.
     """
     n = points.shape[0]
     if weights is None:
@@ -32,7 +36,10 @@ def kmeans_assign(
     if impl == "jnp":
         return kmeans_assign_ref(points, centers, weights)
 
-    tn = min(tile_n, max(8, 1 << (n - 1).bit_length()))
+    interpret = resolve_interpret(interpret)
+    tn = tile_n
+    if interpret:
+        tn = min(tile_n, max(8, 1 << (n - 1).bit_length()))
     pad = (-n) % tn
     if pad:
         points = jnp.concatenate([points, jnp.zeros((pad, points.shape[1]), points.dtype)])

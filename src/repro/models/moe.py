@@ -27,7 +27,6 @@ from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.shuffle import SecureShuffleConfig, bucket_pack, keyed_all_to_all
 from repro.models.layers import _key, act_fn, ninit
 
@@ -250,7 +249,7 @@ def moe_apply(cfg, params, x, *, mesh=None, dp_spec=("pod", "data"),
         else:
             body = partial(_moe_decode_body, cfg=cfg, n_model=n_model, all_axes=all_axes)
             x_spec = P(dp, None, None)
-        fn = compat.shard_map(
+        fn = jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(

@@ -20,8 +20,6 @@ from dataclasses import dataclass, field, replace
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import compat
-
 
 @dataclass(frozen=True)
 class ShardingRules:
@@ -96,7 +94,7 @@ def rules_for_mesh(mesh: Mesh, cfg=None) -> ShardingRules:
 
 def logical_to_spec(axes_tree, rules: ShardingRules):
     """Map a pytree of logical-axes tuples to a pytree of PartitionSpecs."""
-    return compat.tree_map(
+    return jax.tree.map(
         lambda axes: rules.spec(axes),
         axes_tree,
         is_leaf=lambda x: isinstance(x, tuple) and all(isinstance(a, (str, type(None))) for a in x),
@@ -107,5 +105,5 @@ def shard_params_specs(axes_tree, mesh: Mesh, rules: ShardingRules | None = None
     """NamedShardings for a params tree from its logical axes tree."""
     rules = rules or rules_for_mesh(mesh)
     specs = logical_to_spec(axes_tree, rules)
-    return compat.tree_map(lambda s: NamedSharding(mesh, s), specs,
-                           is_leaf=lambda x: isinstance(x, P))
+    return jax.tree.map(lambda s: NamedSharding(mesh, s), specs,
+                        is_leaf=lambda x: isinstance(x, P))

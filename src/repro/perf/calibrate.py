@@ -246,8 +246,6 @@ def _probe_chacha(impl: str, mesh, axis_name: str, widths) -> dict:
 
 
 def _probe_all_to_all(mesh, axis_name: str, sizes) -> dict:
-    from repro import compat
-
     r = mesh.shape[axis_name]
 
     def body(x):
@@ -255,7 +253,7 @@ def _probe_all_to_all(mesh, axis_name: str, sizes) -> dict:
 
     xs, ys = [], []
     for words in sizes:
-        fn = jax.jit(compat.shard_map(
+        fn = jax.jit(jax.shard_map(
             body, mesh=mesh, in_specs=P(axis_name), out_specs=P(axis_name),
             check_vma=False))
         x = jnp.zeros((r * r, max(1, words // r)), jnp.uint32)

@@ -54,8 +54,8 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
-import jax.numpy as jnp
-from jax.sharding import Mesh
+import jax
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.driver import (
     DEFAULT_HALT_LOOP,
@@ -360,7 +360,8 @@ class JobHandle:
     `time.perf_counter()` stamps: `latency_s` spans submit -> finish (what a
     client observes), `queue_s` the pre-admission wait. `runner_misses`
     counts the runner-cache misses charged to THIS job — 0 means the job ran
-    entirely on cached programs (a warm job).
+    entirely on cached programs (a warm job). `input_devices` maps each
+    input leaf to the ids of the devices holding its shards, once placed.
     """
 
     job_id: int
@@ -376,6 +377,7 @@ class JobHandle:
     finished_at: float | None = None
     runner_misses: int = 0
     chunks: int = 0
+    input_devices: dict = field(default_factory=dict)
 
     def result(self, timeout: float | None = None):
         return self.future.result(timeout)
@@ -603,6 +605,13 @@ class SecureJobService:
 
     def _run_chunks(self, spec, inputs, init_state, handle, view, *,
                     max_rounds, min_chunk, max_chunk):
+        # split the host inputs over the mesh axis in one transfer, straight
+        # to the devices that own each shard (never staged on the default
+        # device); the driver then finds them already in the runner's layout
+        inputs = jax.device_put(inputs, NamedSharding(self.mesh, P(self.axis_name)))
+        handle.input_devices = {
+            name: tuple(s.device.id for s in leaf.addressable_shards)
+            for name, leaf in inputs.items()}
         return run_until_chunks(
             spec, inputs, init_state, self.mesh, self.axis_name,
             secure=self.secure, max_rounds=max_rounds,
@@ -658,10 +667,8 @@ class SecureJobService:
             pts[:n] = points
             wts = np.zeros((bucket,), np.float32)  # padding weight 0: inert
             wts[:n] = weights
-            inputs = {"p": jnp.asarray(pts), "w": jnp.asarray(wts)}
-            init = {"c": jnp.asarray(init_centers),
-                    "thr": jnp.float32(threshold)}
-            return self._run_chunks(spec, inputs, init, handle, view,
+            init = {"c": init_centers, "thr": np.float32(threshold)}
+            return self._run_chunks(spec, {"p": pts, "w": wts}, init, handle, view,
                                     max_rounds=max_rounds,
                                     min_chunk=min_chunk, max_chunk=max_chunk)
 
@@ -720,12 +727,12 @@ class SecureJobService:
             edges = np.asarray(lo + span * np.arange(r + 1) / r, np.float32)
             edges[-1] = hi + 1e-3 * span  # open top edge keeps hi in-bucket
             init = {
-                "edges": jnp.asarray(edges),
-                "sorted": jnp.full((r, r * capacity), jnp.inf, jnp.float32),
-                "counts": jnp.zeros((r,), jnp.float32),
-                "total": jnp.float32(n),
+                "edges": edges,
+                "sorted": np.full((r, r * capacity), np.inf, np.float32),
+                "counts": np.zeros((r,), np.float32),
+                "total": np.float32(n),
             }
-            return self._run_chunks(spec, {"v": jnp.asarray(vals)}, init,
+            return self._run_chunks(spec, {"v": vals}, init,
                                     handle, view, max_rounds=max_rounds,
                                     min_chunk=min_chunk, max_chunk=max_chunk)
 
@@ -782,9 +789,9 @@ class SecureJobService:
         def make_gen(handle):
             toks = np.full((bucket,), -1, np.int32)  # -1: matches no pattern
             toks[:n] = tokens
-            init = {"hits": jnp.zeros((patterns.shape[0],), jnp.float32),
-                    "cursor": jnp.uint32(0)}
-            return self._run_chunks(spec, {"t": jnp.asarray(toks)}, init,
+            init = {"hits": np.zeros((patterns.shape[0],), np.float32),
+                    "cursor": np.uint32(0)}
+            return self._run_chunks(spec, {"t": toks}, init,
                                     handle, view, max_rounds=n_rounds,
                                     min_chunk=min_chunk, max_chunk=max_chunk)
 

@@ -166,3 +166,33 @@ def test_run_until_donates_but_preserves_callers_state():
     np.testing.assert_array_equal(np.asarray(res.state), np.asarray(ref.state))
     np.testing.assert_array_equal(np.asarray(res.aux["total"]),
                                   np.asarray(ref.aux["total"]))
+
+
+def test_run_until_preserves_callers_state_when_it_reshards():
+    """Placing a one-device init_state onto a replicated 4-device layout
+    can reuse the caller's buffer for device 0; run_until must still copy
+    it before the first chunk donates, so the caller's array survives."""
+    from conftest import run_in_subprocess
+
+    out = run_in_subprocess("""
+        import jax, jax.numpy as jnp
+        from repro.compat import make_mesh
+        from repro.core.driver import IterativeSpec, run_until
+        from repro.core.engine import identity_hash
+
+        def reduce_fn(state, rk, rv, valid, r):
+            got = jax.lax.psum(jnp.sum(jnp.where(valid, rv["v"], 0.0)), "data")
+            return state + got, {"total": state + got}
+
+        spec = IterativeSpec(
+            map_fn=lambda state, inputs, r: (inputs["k"], {"v": inputs["v"]}),
+            reduce_fn=reduce_fn, hash_fn=identity_hash, capacity=4)
+        mesh = make_mesh((4,), ("data",))
+        inputs = {"k": jnp.arange(8, dtype=jnp.int32) % 4,
+                  "v": jnp.ones((8,), jnp.float32)}
+        init = jnp.float32(1.0)
+        res = run_until(spec, inputs, init, mesh, max_rounds=2)
+        assert not init.is_deleted()
+        print("init", float(init), "state", float(res.state))
+    """, devices=4)
+    assert "init 1.0 state 17.0" in out, out

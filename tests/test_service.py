@@ -291,11 +291,14 @@ def test_lru_eviction_of_live_jobs_runner_is_bitidentical():
         with SecureJobService(_mesh1(), secure=_secure_cfg(), cache=cache,
                               max_concurrent=2) as svc:
             # different pattern sets -> different cache keys; fixed chunk
-            # size 1 -> each job needs its runner on every pass
-            ha = svc.submit_grep(toks, [1, 2], n_rounds=2,
-                                 min_chunk=1, max_chunk=1)
-            hb = svc.submit_grep(toks, [3, 4, 5], n_rounds=2,
-                                 min_chunk=1, max_chunk=1)
+            # size 1 -> each job needs its runner on every pass. Both are
+            # queued before the scheduler can admit either (it waits on this
+            # lock), so they are live together from the first pass
+            with svc._cv:
+                ha = svc.submit_grep(toks, [1, 2], n_rounds=2,
+                                     min_chunk=1, max_chunk=1)
+                hb = svc.submit_grep(toks, [3, 4, 5], n_rounds=2,
+                                     min_chunk=1, max_chunk=1)
             return ha.result(timeout=600), hb.result(timeout=600)
 
     capped = RunnerCache(max_resident=1)

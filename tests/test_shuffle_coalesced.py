@@ -27,7 +27,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.compat import make_mesh
 from repro.core import shuffle
 from repro.core.shuffle import (
@@ -160,14 +159,14 @@ def test_keyed_all_to_all_layouts_agree_end_to_end():
     mesh = make_mesh((1,), ("data",))
     rng = np.random.default_rng(11)
     tree = _random_tree(rng, 1, 5)
-    specs = compat.tree_map(lambda _: P("data"), tree)
+    specs = jax.tree.map(lambda _: P("data"), tree)
 
     def run(sec, coalesce=None):
         body = lambda t: keyed_all_to_all(t, "data", sec,
                                           round_index=jnp.uint32(7),
                                           coalesce=coalesce)
-        fn = compat.shard_map(body, mesh=mesh, in_specs=(specs,), out_specs=specs,
-                              check_vma=False)
+        fn = jax.shard_map(body, mesh=mesh, in_specs=(specs,), out_specs=specs,
+                           check_vma=False)
         return jax.jit(fn)(tree)
 
     with record_wire_bytes() as recs:

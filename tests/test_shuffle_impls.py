@@ -22,7 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.compat import make_mesh
 from repro.core import shuffle
 from repro.core.shuffle import (
@@ -227,12 +226,12 @@ def test_wire_bytes_secure_equals_plain():
         "k": jnp.arange(8, dtype=jnp.int32).reshape(1, 8),
         "v": jnp.ones((1, 8, 2), jnp.float32),
     }
-    specs = compat.tree_map(lambda _: P("data"), tree)
+    specs = jax.tree.map(lambda _: P("data"), tree)
 
     def run(secure):
         body = lambda t: keyed_all_to_all(t, "data", secure)
-        fn = compat.shard_map(body, mesh=mesh, in_specs=(specs,), out_specs=specs,
-                              check_vma=False)
+        fn = jax.shard_map(body, mesh=mesh, in_specs=(specs,), out_specs=specs,
+                           check_vma=False)
         return jax.jit(fn)(tree)
 
     with record_wire_bytes() as recs:
