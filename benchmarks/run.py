@@ -104,7 +104,7 @@ ENVELOPE = ("schema", "smoke", "backend", "platform", "jax")
 def _warn_stale_sections(path: str, owned: set) -> None:
     """Warn when an existing artifact holds sections this run won't rewrite.
 
-    Checked-in BENCH_*.json files outlive module renames; a section nobody
+    A BENCH_*.json file left from an earlier run outlives module renames; a section nobody
     owns any more (e.g. a leftover ``bench_oblivious``) would silently pin
     numbers from an old HEAD forever. The rewrite below drops it — this
     warning makes the drop visible in the CI log.

@@ -207,7 +207,7 @@ with it unset, every `auto` resolves to its historical default bit-for-bit.
 
 `repro/perf/model.py` documents what each recommendation minimizes;
 `benchmarks/bench_costmodel.py` reports predicted-vs-measured error
-(BENCH_costmodel.json `pred_error`) so the calibration stays honest, and
+(`pred_error`) so the calibration stays honest, and
 `launch/hillclimb.py --cell K` ranks full knob vectors offline by
 predicted AdmissionSim makespan.
 """
@@ -227,6 +227,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core.engine import default_hash
 from repro.core.shuffle import (
     SecureShuffleConfig,
@@ -516,7 +517,8 @@ class IterativeSpec:
 def _round_body(state, r, *, inputs, spec: IterativeSpec, axis_name: str, n_shards: int,
                 secure: SecureShuffleConfig | None, coalesce=None,
                 trace_info: dict | None = None):
-    mk, mv = spec.map_fn(state, inputs, r)
+    with jax.named_scope(obs.MAP):
+        mk, mv = spec.map_fn(state, inputs, r)
     if spec.combine_fn is not None:
         mk, mv = spec.combine_fn(mk, mv)
     n_mapped = mk.shape[0]
@@ -537,7 +539,8 @@ def _round_body(state, r, *, inputs, spec: IterativeSpec, axis_name: str, n_shar
     flat_v = jax.tree.map(lambda x: x.reshape((-1,) + x.shape[2:]), recv["v"])
     valid = flat_k >= 0
 
-    new_state, aux = spec.reduce_fn(state, flat_k, flat_v, valid, r)
+    with jax.named_scope(obs.REDUCE):
+        new_state, aux = spec.reduce_fn(state, flat_k, flat_v, valid, r)
     return new_state, (aux, lax.psum(dropped, axis_name))
 
 
@@ -577,7 +580,8 @@ def _halting_shard_body(inputs, state, round_offset, *, spec: IterativeSpec, axi
 
     def _halt(new_state, aux, r):
         guarded = _guard_state_for_halt(new_state, state_spec_tree, flat_sharded)
-        return jnp.reshape(jnp.asarray(spec.halt_fn(guarded, aux, r), jnp.bool_), ())
+        with jax.named_scope(obs.HALT):
+            return jnp.reshape(jnp.asarray(spec.halt_fn(guarded, aux, r), jnp.bool_), ())
 
     if loop_impl == "while":
         aux0 = jax.tree.map(lambda s: jnp.zeros((n_rounds,) + s.shape, s.dtype), aux_sds)
@@ -714,12 +718,39 @@ def make_iterative_runner(
     jitted = jax.jit(run, donate_argnums=(1,) if donate_state else ())
 
     def runner(inputs, state, round_offset=0):
+        if runner.arg_specs is None:
+            # before the call: a donated state is deleted by it
+            runner.arg_specs = jax.tree.map(_arg_spec, (inputs, state, round_offset))
         return jitted(inputs, state, round_offset)
+
+    layers = None
+
+    def op_layers():
+        """Each instruction of the compiled program, up to `, metadata=`,
+        mapped to its innermost layer scope (`repro.obs.op_layers`); None
+        before any call. Lowers again from the first call's argument specs,
+        once per runner: JAX's compile caches hand back the program that
+        ran, so a reader pays no compile and an untraced run nothing."""
+        nonlocal layers
+        if layers is None and runner.arg_specs is not None:
+            compiled = jitted.lower(*runner.arg_specs).compile()
+            layers = obs.op_layers(compiled.as_text())
+        return layers
 
     runner.trace_info = trace_info
     runner.abstract_fn = run  # un-jitted body, for make_jaxpr inspection
     runner.jitted = jitted  # exposes .lower() for donation/lowering audits
+    runner.arg_specs = None  # (inputs, state, round_offset) of the first call
+    runner.op_layers = op_layers
     return runner
+
+
+def _arg_spec(x):
+    """An array argument's shape, dtype and sharding, without its data."""
+    if not hasattr(x, "shape"):
+        return x  # a Python scalar: traced as its weak type, holds no data
+    return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=getattr(x, "sharding", None),
+                                weak_type=getattr(x, "weak_type", False))
 
 
 def _warn_overflow(dropped, first_round: int, trace_info: dict | None, stacklevel: int = 3):
@@ -942,20 +973,22 @@ def run_until_chunks(
     # default device and re-split every chunk), state in its per-leaf specs
     # — the sharding every chunk returns it in, so chunk 2 sees the aval
     # chunk 1 was traced with and reuses its program
-    inputs = jax.device_put(inputs, NamedSharding(mesh, P(axis_name)))
-    state_spec_tree, _ = _resolve_state_specs(spec, init_state)
-    state = jax.device_put(
-        init_state,
-        jax.tree.map(lambda p: NamedSharding(mesh, p), state_spec_tree,
-                     is_leaf=lambda x: isinstance(x, P)))
-    if donate_state:
-        # placement may hand back the caller's own device buffer (even when
-        # it reshards), so copy every leaf that came in as a device array:
-        # the first chunk's donation must not delete init_state. All later
-        # chunks donate run_until's own output state, which nothing else holds
-        state = jax.tree.map(
-            lambda src, x: x.copy() if isinstance(src, jax.Array) else x,
-            init_state, state)
+    with obs.span(obs.PREPARE, job=job_tag):
+        inputs = jax.device_put(inputs, NamedSharding(mesh, P(axis_name)))
+        state_spec_tree, _ = _resolve_state_specs(spec, init_state)
+        state = jax.device_put(
+            init_state,
+            jax.tree.map(lambda p: NamedSharding(mesh, p), state_spec_tree,
+                         is_leaf=lambda x: isinstance(x, P)))
+        if donate_state:
+            # placement may hand back the caller's own device buffer (even
+            # when it reshards), so copy every leaf that came in as a device
+            # array: the first chunk's donation must not delete init_state.
+            # All later chunks donate run_until's own output state, which
+            # nothing else holds
+            state = jax.tree.map(
+                lambda src, x: x.copy() if isinstance(src, jax.Array) else x,
+                init_state, state)
     executed = dispatched = n_dispatches = 0
     halted = False
     aux_chunks: list = []
@@ -977,18 +1010,20 @@ def run_until_chunks(
             runner = runners.get(n)
             if runner is None:
                 runner = runners[n] = build()
-        with wire_accounting.tagged(job_tag):
+        with wire_accounting.tagged(job_tag), \
+                obs.span(obs.DISPATCH, job=job_tag, chunk=n_dispatches):
             out = runner(inputs, state, round_offset + executed)
-        if spec.halt_fn is None:
-            state, aux, dropped = out
-            n_exec, chunk_halted = n, False
-        else:
-            state, aux, dropped, n_exec, chunk_halted = out
-            n_exec, chunk_halted = int(n_exec), bool(chunk_halted)
+        with obs.span(obs.READBACK, job=job_tag, chunk=n_dispatches):
+            if spec.halt_fn is None:
+                state, aux, dropped = out
+                n_exec, chunk_halted = n, False
+            else:
+                state, aux, dropped, n_exec, chunk_halted = out
+                n_exec, chunk_halted = int(n_exec), bool(chunk_halted)
+            aux_chunks.append(jax.tree.map(lambda a: np.asarray(a)[:n_exec], aux))
+            dropped_chunks.append(np.asarray(dropped)[:n_exec])
         n_dispatches += 1
         dispatched += n
-        aux_chunks.append(jax.tree.map(lambda a: np.asarray(a)[:n_exec], aux))
-        dropped_chunks.append(np.asarray(dropped)[:n_exec])
         if warn_on_overflow and overflow_trace_info is None and np.any(
                 dropped_chunks[-1] > 0):
             overflow_trace_info = dict(runner.trace_info)

@@ -112,6 +112,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from repro import obs
 from repro.crypto import ctr as _ctr
 from repro.crypto.ctr import words_for
 from repro.kernels import resolve_interpret
@@ -259,38 +260,39 @@ def bucket_pack(keys, bucket, values, n_buckets: int, capacity: int,
       n_dropped  () int32 — items lost to capacity overflow
       [, positions (n,) int32].
     """
-    n = keys.shape[0]
-    valid = keys >= 0
-    b = jnp.where(valid, bucket, n_buckets)  # invalid items sort last
-    order = jnp.argsort(b, stable=True)
-    b_sorted = b[order]
-    # position within bucket: i - first occurrence of this bucket value
-    first = jnp.searchsorted(b_sorted, b_sorted, side="left")
-    pos = jnp.arange(n, dtype=jnp.int32) - first.astype(jnp.int32)
-    in_range = (b_sorted < n_buckets) & (pos < capacity)
-    dest = jnp.where(in_range, b_sorted * capacity + pos, n_buckets * capacity)
-    n_dropped = jnp.sum((b_sorted < n_buckets) & (pos >= capacity)).astype(jnp.int32)
+    with jax.named_scope(obs.BUCKET_PACK):
+        n = keys.shape[0]
+        valid = keys >= 0
+        b = jnp.where(valid, bucket, n_buckets)  # invalid items sort last
+        order = jnp.argsort(b, stable=True)
+        b_sorted = b[order]
+        # position within bucket: i - first occurrence of this bucket value
+        first = jnp.searchsorted(b_sorted, b_sorted, side="left")
+        pos = jnp.arange(n, dtype=jnp.int32) - first.astype(jnp.int32)
+        in_range = (b_sorted < n_buckets) & (pos < capacity)
+        dest = jnp.where(in_range, b_sorted * capacity + pos, n_buckets * capacity)
+        n_dropped = jnp.sum((b_sorted < n_buckets) & (pos >= capacity)).astype(jnp.int32)
 
-    def scatter(x_sorted, fill):
-        if any(d == 0 for d in x_sorted.shape[1:]):
-            # Zero-size trailing dims (e.g. a (n, 0) per-item leaf): the
-            # n_buckets*capacity+1 overflow-slot scatter below degenerates —
-            # there are no elements to place, only shapes to produce — so
-            # return the empty fixed-shape buffer directly instead of
-            # emitting a 0-element XLA scatter.
-            return jnp.zeros((n_buckets, capacity) + x_sorted.shape[1:], x_sorted.dtype)
-        out = jnp.full((n_buckets * capacity + 1,) + x_sorted.shape[1:], fill, x_sorted.dtype)
-        out = out.at[dest].set(x_sorted)
-        return out[:-1].reshape((n_buckets, capacity) + x_sorted.shape[1:])
+        def scatter(x_sorted, fill):
+            if any(d == 0 for d in x_sorted.shape[1:]):
+                # Zero-size trailing dims (e.g. a (n, 0) per-item leaf): the
+                # n_buckets*capacity+1 overflow-slot scatter below degenerates —
+                # there are no elements to place, only shapes to produce — so
+                # return the empty fixed-shape buffer directly instead of
+                # emitting a 0-element XLA scatter.
+                return jnp.zeros((n_buckets, capacity) + x_sorted.shape[1:], x_sorted.dtype)
+            out = jnp.full((n_buckets * capacity + 1,) + x_sorted.shape[1:], fill, x_sorted.dtype)
+            out = out.at[dest].set(x_sorted)
+            return out[:-1].reshape((n_buckets, capacity) + x_sorted.shape[1:])
 
-    out_keys = scatter(keys[order], jnp.int32(-1))
-    out_values = jax.tree.map(lambda v: scatter(v[order], jnp.zeros((), v.dtype)), values)
-    if not return_positions:
-        return out_keys, out_values, n_dropped
-    positions = jnp.full((n,), n_buckets * capacity, jnp.int32).at[order].set(
-        dest.astype(jnp.int32)
-    )
-    return out_keys, out_values, n_dropped, positions
+        out_keys = scatter(keys[order], jnp.int32(-1))
+        out_values = jax.tree.map(lambda v: scatter(v[order], jnp.zeros((), v.dtype)), values)
+        if not return_positions:
+            return out_keys, out_values, n_dropped
+        positions = jnp.full((n,), n_buckets * capacity, jnp.int32).at[order].set(
+            dest.astype(jnp.int32)
+        )
+        return out_keys, out_values, n_dropped, positions
 
 
 def _row_blocks(leaf_row_shape, dtype) -> int:
@@ -368,7 +370,8 @@ def _crypt_wires(wires, meta, cfg, nonce_ids, ctr_rows, round_id=None):
         r, n_words = words.shape
         blocks = _row_blocks(shape[1:], dtype)
         ctr_starts = offset + ctr_rows * jnp.uint32(blocks)
-        out.append(_crypt_rows(cfg, words, nonce_ids, ctr_starts, round_id))
+        with jax.named_scope(obs.KEYSTREAM):
+            out.append(_crypt_rows(cfg, words, nonce_ids, ctr_starts, round_id))
         offset = offset + jnp.uint32(blocks * r)
     return out
 
@@ -484,17 +487,18 @@ def _crypt_wire_coalesced(wire, layout: _WireLayout, cfg, nonce_ids, ctr_rows,
     """
     if layout.total_blocks == 0:
         return wire
-    nonce_ids = jnp.asarray(nonce_ids, jnp.uint32)
-    ctr_rows = jnp.asarray(ctr_rows, jnp.uint32)
-    ctr_base = jnp.uint32(cfg.counter0) + jnp.asarray(layout.ctr_base, jnp.uint32)
-    ctr_rowmul = jnp.asarray(layout.ctr_rowmul, jnp.uint32)
-    zeros = jnp.zeros((wire.shape[0], layout.total_words), jnp.uint32)
-    impl, interpret = resolve_chacha_impl(cfg.impl)
-    state0 = make_state0(cfg.key_words, _round_nonce(cfg, round_id), 0)
-    ks = chacha20_xor_rows_coalesced(zeros, state0, nonce_ids, ctr_rows,
-                                     ctr_base, ctr_rowmul,
-                                     impl=impl, interpret=interpret)
-    return wire ^ _packed_keystream(ks, layout)
+    with jax.named_scope(obs.KEYSTREAM):
+        nonce_ids = jnp.asarray(nonce_ids, jnp.uint32)
+        ctr_rows = jnp.asarray(ctr_rows, jnp.uint32)
+        ctr_base = jnp.uint32(cfg.counter0) + jnp.asarray(layout.ctr_base, jnp.uint32)
+        ctr_rowmul = jnp.asarray(layout.ctr_rowmul, jnp.uint32)
+        zeros = jnp.zeros((wire.shape[0], layout.total_words), jnp.uint32)
+        impl, interpret = resolve_chacha_impl(cfg.impl)
+        state0 = make_state0(cfg.key_words, _round_nonce(cfg, round_id), 0)
+        ks = chacha20_xor_rows_coalesced(zeros, state0, nonce_ids, ctr_rows,
+                                         ctr_base, ctr_rowmul,
+                                         impl=impl, interpret=interpret)
+        return wire ^ _packed_keystream(ks, layout)
 
 
 class _WireAccounting:
@@ -673,7 +677,8 @@ def keyed_all_to_all(tree, axis_name: str, secure: SecureShuffleConfig | None = 
                 per_leaf=[m[4] * r * 4 for m in layout.leaves],
                 collectives=1,
             )
-            wire = lax.all_to_all(wire, axis_name, 0, 0, tiled=True)
+            with jax.named_scope(obs.EXCHANGE):
+                wire = lax.all_to_all(wire, axis_name, 0, 0, tiled=True)
             return _unpack_wire_coalesced(wire, layout, treedef)
         wire_accounting.note(
             secure=False,
@@ -682,7 +687,8 @@ def keyed_all_to_all(tree, axis_name: str, secure: SecureShuffleConfig | None = 
             per_leaf=raw_bytes,
             collectives=len(leaves),
         )
-        return jax.tree.map(lambda x: lax.all_to_all(x, axis_name, 0, 0, tiled=True), tree)
+        with jax.named_scope(obs.EXCHANGE):
+            return jax.tree.map(lambda x: lax.all_to_all(x, axis_name, 0, 0, tiled=True), tree)
 
     r = jax.tree.leaves(tree)[0].shape[0]
     idx = lax.axis_index(axis_name).astype(jnp.uint32)
@@ -710,7 +716,8 @@ def keyed_all_to_all(tree, axis_name: str, secure: SecureShuffleConfig | None = 
         )
         wire = _crypt_wire_coalesced(wire, layout, secure, my_id, dest_rows,
                                      round_index)
-        wire = lax.all_to_all(wire, axis_name, 0, 0, tiled=True)
+        with jax.named_scope(obs.EXCHANGE):
+            wire = lax.all_to_all(wire, axis_name, 0, 0, tiled=True)
         wire = _crypt_wire_coalesced(wire, layout, secure, src_ids, my_rows,
                                      round_index)
         return _unpack_wire_coalesced(wire, layout, treedef)
@@ -728,7 +735,8 @@ def keyed_all_to_all(tree, axis_name: str, secure: SecureShuffleConfig | None = 
 
     wires = _crypt_wires(wires, meta, secure, my_id, dest_rows, round_index)
 
-    wires = [lax.all_to_all(w, axis_name, 0, 0, tiled=True) for w in wires]
+    with jax.named_scope(obs.EXCHANGE):
+        wires = [lax.all_to_all(w, axis_name, 0, 0, tiled=True) for w in wires]
 
     wires = _crypt_wires(wires, meta, secure, src_ids, my_rows, round_index)
     return _unpack_wire(wires, meta, treedef)

@@ -50,6 +50,7 @@ import threading
 import time
 from collections import OrderedDict, deque
 from concurrent.futures import Future
+from contextlib import contextmanager
 from dataclasses import dataclass, field
 
 import numpy as np
@@ -57,6 +58,7 @@ import numpy as np
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro import obs
 from repro.core.driver import (
     DEFAULT_HALT_LOOP,
     resolve_state_mode,
@@ -357,11 +359,15 @@ class JobHandle:
 
     `result(timeout)` blocks for the job's finalized output (a plain dict of
     numpy arrays; see the `submit_*` docstrings). Timing fields are
-    `time.perf_counter()` stamps: `latency_s` spans submit -> finish (what a
-    client observes), `queue_s` the pre-admission wait. `runner_misses`
-    counts the runner-cache misses charged to THIS job — 0 means the job ran
-    entirely on cached programs (a warm job). `input_devices` maps each
-    input leaf to the ids of the devices holding its shards, once placed.
+    `time.perf_counter()` stamps, `submitted_at` taken on entry to
+    `submit_*`: `latency_s` spans submit -> finish (what a client observes,
+    the submit's own host work included), `queue_s` the wait until the
+    scheduler starts the job. `runner_misses` counts the runner-cache misses
+    charged to THIS job — 0 means the job ran entirely on cached programs (a
+    warm job). `runners` holds the runners its chunks dispatched, whose
+    `op_layers()` maps the compiled program to layers. `input_devices` maps
+    each input leaf to the ids of the devices holding its shards, once
+    placed.
     """
 
     job_id: int
@@ -377,6 +383,7 @@ class JobHandle:
     finished_at: float | None = None
     runner_misses: int = 0
     chunks: int = 0
+    runners: list = field(default_factory=list, repr=False)
     input_devices: dict = field(default_factory=dict)
 
     def result(self, timeout: float | None = None):
@@ -417,6 +424,8 @@ class _JobRunners:
         before = self._view.cache.misses
         runner = self._view.get_or_build(n_rounds, build)
         self._handle.runner_misses += self._view.cache.misses - before
+        if not any(r is runner for r in self._handle.runners):
+            self._handle.runners.append(runner)
         return runner
 
 
@@ -550,7 +559,8 @@ class SecureJobService:
                 try:
                     if job.gen is None:
                         job.handle.started_at = time.perf_counter()
-                        job.gen = job.make_gen(job.handle)
+                        with obs.span(obs.PREPARE, job=job.handle.job_id):
+                            job.gen = job.make_gen(job.handle)
                     next(job.gen)
                     job.handle.chunks += 1
                 except StopIteration as stop:
@@ -561,7 +571,8 @@ class SecureJobService:
     def _finish(self, job: _Job, res, exc=None):
         if exc is None:
             try:
-                value = job.finalize(res)
+                with obs.span(obs.FINALIZE, job=job.handle.job_id):
+                    value = job.finalize(res)
             except BaseException as finalize_exc:
                 exc = finalize_exc
         job.handle.finished_at = time.perf_counter()
@@ -574,20 +585,31 @@ class SecureJobService:
         else:
             job.handle.future.set_result(value)
 
-    def _submit(self, kind, n, bucket, max_rounds, make_gen, finalize,
+    @contextmanager
+    def _submission(self):
+        """Open a `submit_*` call: stamp it, take its job id and open its
+        `repro.submit` span, which closes once `_submit` has enqueued it."""
+        t0 = time.perf_counter()
+        with self._cv:
+            job_id = self._next_id
+            self._next_id += 1
+        with obs.span(obs.SUBMIT, job=job_id):
+            yield job_id, t0
+
+    def _submit(self, ticket, kind, n, bucket, max_rounds, make_gen, finalize,
                 priority: int = 0) -> JobHandle:
         priority = int(priority)
         if priority < 0:
             raise ValueError(f"priority must be >= 0, got {priority}")
+        job_id, submitted_at = ticket
         with self._cv:
             if self._closed:
                 raise RuntimeError("SecureJobService is closed")
             handle = JobHandle(
-                job_id=self._next_id, kind=kind, n=n, bucket=bucket,
+                job_id=job_id, kind=kind, n=n, bucket=bucket,
                 round_base=self._round_base, max_rounds=max_rounds,
-                priority=priority, submitted_at=time.perf_counter(),
+                priority=priority, submitted_at=submitted_at,
             )
-            self._next_id += 1
             # keystream disjointness across jobs: reserve this job's whole
             # round budget on the monotone per-service counter
             self._round_base += max_rounds
@@ -639,50 +661,51 @@ class SecureJobService:
         `priority > 0` admits ahead of the normal FIFO class (active jobs
         are never preempted).
         """
-        points = np.asarray(points, np.float32)
-        if points.ndim != 2 or points.shape[0] < 1:
-            raise ValueError(f"points must be (n, d) with n >= 1, got {points.shape}")
-        n, d = points.shape
-        if not 1 <= k <= n:
-            raise ValueError(f"k must be in [1, n={n}], got {k}")
-        if weights is None:
-            weights = np.ones((n,), np.float32)
-        weights = np.asarray(weights, np.float32)
-        if init_centers is None:
-            init_centers = points[:k]
-        init_centers = np.asarray(init_centers, np.float32)
-        if threshold is None:
-            diag = float(np.linalg.norm(points.max(axis=0) - points.min(axis=0)))
-            threshold = diag / 1000.0  # paper §V
-        bucket = bucket_for(n, multiple=self.n_shards, growth=self.bucket_growth)
-        spec = make_kmeans_iterative_spec(
-            k, self.n_shards, impl=self.kmeans_impl, axis_name=self.axis_name,
-            runtime_threshold=True)
-        view = self._view(("kmeans", k, d, self.kmeans_impl, bucket))
-        min_chunk = self.min_chunk if min_chunk is None else min_chunk
-        max_chunk = self.max_chunk if max_chunk is None else max_chunk
+        with self._submission() as ticket:
+            points = np.asarray(points, np.float32)
+            if points.ndim != 2 or points.shape[0] < 1:
+                raise ValueError(f"points must be (n, d) with n >= 1, got {points.shape}")
+            n, d = points.shape
+            if not 1 <= k <= n:
+                raise ValueError(f"k must be in [1, n={n}], got {k}")
+            if weights is None:
+                weights = np.ones((n,), np.float32)
+            weights = np.asarray(weights, np.float32)
+            if init_centers is None:
+                init_centers = points[:k]
+            init_centers = np.asarray(init_centers, np.float32)
+            if threshold is None:
+                diag = float(np.linalg.norm(points.max(axis=0) - points.min(axis=0)))
+                threshold = diag / 1000.0  # paper §V
+            bucket = bucket_for(n, multiple=self.n_shards, growth=self.bucket_growth)
+            spec = make_kmeans_iterative_spec(
+                k, self.n_shards, impl=self.kmeans_impl, axis_name=self.axis_name,
+                runtime_threshold=True)
+            view = self._view(("kmeans", k, d, self.kmeans_impl, bucket))
+            min_chunk = self.min_chunk if min_chunk is None else min_chunk
+            max_chunk = self.max_chunk if max_chunk is None else max_chunk
 
-        def make_gen(handle):
-            pts = np.zeros((bucket, d), np.float32)
-            pts[:n] = points
-            wts = np.zeros((bucket,), np.float32)  # padding weight 0: inert
-            wts[:n] = weights
-            init = {"c": init_centers, "thr": np.float32(threshold)}
-            return self._run_chunks(spec, {"p": pts, "w": wts}, init, handle, view,
-                                    max_rounds=max_rounds,
-                                    min_chunk=min_chunk, max_chunk=max_chunk)
+            def make_gen(handle):
+                pts = np.zeros((bucket, d), np.float32)
+                pts[:n] = points
+                wts = np.zeros((bucket,), np.float32)  # padding weight 0: inert
+                wts[:n] = weights
+                init = {"c": init_centers, "thr": np.float32(threshold)}
+                return self._run_chunks(spec, {"p": pts, "w": wts}, init, handle, view,
+                                        max_rounds=max_rounds,
+                                        min_chunk=min_chunk, max_chunk=max_chunk)
 
-        def finalize(res):
-            return {
-                "centers": np.asarray(res.state["c"]),
-                "n_iter": res.rounds_executed,
-                "shifts": np.asarray(res.aux["shift"]),
-                "halted": res.halted,
-                "n_dispatches": res.n_dispatches,
-            }
+            def finalize(res):
+                return {
+                    "centers": np.asarray(res.state["c"]),
+                    "n_iter": res.rounds_executed,
+                    "shifts": np.asarray(res.aux["shift"]),
+                    "halted": res.halted,
+                    "n_dispatches": res.n_dispatches,
+                }
 
-        return self._submit("kmeans", n, bucket, max_rounds, make_gen, finalize,
-                            priority=priority)
+            return self._submit(ticket, "kmeans", n, bucket, max_rounds, make_gen, finalize,
+                                priority=priority)
 
     def submit_sort(self, values, *, balance: float = 1.5, max_rounds: int = 4,
                     lo: float | None = None, hi: float | None = None,
@@ -699,57 +722,58 @@ class SecureJobService:
         shuffled. Per-(source, dest) capacity defaults to the bucket's
         lossless worst case.
         """
-        values = np.asarray(values, np.float32)
-        if values.ndim != 1 or values.shape[0] < 1:
-            raise ValueError(f"values must be (n,) with n >= 1, got {values.shape}")
-        n = values.shape[0]
-        r = self.n_shards
-        bucket = bucket_for(n, multiple=r, growth=self.bucket_growth)
-        if capacity is None:
-            rec = _model_recommendation("sort_capacity", bucket=bucket, n_shards=r)
-            capacity = bucket // r if rec is None else int(rec)
-        if lo is None:
-            lo = float(values.min())
-        if hi is None:
-            hi = float(values.max())
-        span = max(hi - lo, 1e-6)
-        spec = make_sample_sort_spec(
-            r, capacity, axis_name=self.axis_name, balance=balance,
-            shard_state=self.state_mode, dynamic_total=True)
-        view = self._view(("sort", r, capacity, float(balance),
-                           self.state_mode, bucket))
-        min_chunk = self.min_chunk if min_chunk is None else min_chunk
-        max_chunk = self.max_chunk if max_chunk is None else max_chunk
+        with self._submission() as ticket:
+            values = np.asarray(values, np.float32)
+            if values.ndim != 1 or values.shape[0] < 1:
+                raise ValueError(f"values must be (n,) with n >= 1, got {values.shape}")
+            n = values.shape[0]
+            r = self.n_shards
+            bucket = bucket_for(n, multiple=r, growth=self.bucket_growth)
+            if capacity is None:
+                rec = _model_recommendation("sort_capacity", bucket=bucket, n_shards=r)
+                capacity = bucket // r if rec is None else int(rec)
+            if lo is None:
+                lo = float(values.min())
+            if hi is None:
+                hi = float(values.max())
+            span = max(hi - lo, 1e-6)
+            spec = make_sample_sort_spec(
+                r, capacity, axis_name=self.axis_name, balance=balance,
+                shard_state=self.state_mode, dynamic_total=True)
+            view = self._view(("sort", r, capacity, float(balance),
+                               self.state_mode, bucket))
+            min_chunk = self.min_chunk if min_chunk is None else min_chunk
+            max_chunk = self.max_chunk if max_chunk is None else max_chunk
 
-        def make_gen(handle):
-            vals = np.full((bucket,), np.inf, np.float32)  # +inf: inert pad
-            vals[:n] = values
-            edges = np.asarray(lo + span * np.arange(r + 1) / r, np.float32)
-            edges[-1] = hi + 1e-3 * span  # open top edge keeps hi in-bucket
-            init = {
-                "edges": edges,
-                "sorted": np.full((r, r * capacity), np.inf, np.float32),
-                "counts": np.zeros((r,), np.float32),
-                "total": np.float32(n),
-            }
-            return self._run_chunks(spec, {"v": vals}, init,
-                                    handle, view, max_rounds=max_rounds,
-                                    min_chunk=min_chunk, max_chunk=max_chunk)
+            def make_gen(handle):
+                vals = np.full((bucket,), np.inf, np.float32)  # +inf: inert pad
+                vals[:n] = values
+                edges = np.asarray(lo + span * np.arange(r + 1) / r, np.float32)
+                edges[-1] = hi + 1e-3 * span  # open top edge keeps hi in-bucket
+                init = {
+                    "edges": edges,
+                    "sorted": np.full((r, r * capacity), np.inf, np.float32),
+                    "counts": np.zeros((r,), np.float32),
+                    "total": np.float32(n),
+                }
+                return self._run_chunks(spec, {"v": vals}, init,
+                                        handle, view, max_rounds=max_rounds,
+                                        min_chunk=min_chunk, max_chunk=max_chunk)
 
-        def finalize(res):
-            rows = np.asarray(res.state["sorted"])
-            counts = np.asarray(res.state["counts"])
-            out = np.concatenate([rows[i, : int(counts[i])] for i in range(r)])
-            return {
-                "sorted": out,
-                "counts": counts,
-                "rounds": res.rounds_executed,
-                "halted": res.halted,
-                "dropped": np.asarray(res.dropped),
-            }
+            def finalize(res):
+                rows = np.asarray(res.state["sorted"])
+                counts = np.asarray(res.state["counts"])
+                out = np.concatenate([rows[i, : int(counts[i])] for i in range(r)])
+                return {
+                    "sorted": out,
+                    "counts": counts,
+                    "rounds": res.rounds_executed,
+                    "halted": res.halted,
+                    "dropped": np.asarray(res.dropped),
+                }
 
-        return self._submit("sort", n, bucket, max_rounds, make_gen, finalize,
-                            priority=priority)
+            return self._submit(ticket, "sort", n, bucket, max_rounds, make_gen, finalize,
+                                priority=priority)
 
     def submit_grep(self, tokens, patterns, *, n_rounds: int = 4,
                     max_matches: int | None = None,
@@ -765,43 +789,44 @@ class SecureJobService:
         `max_matches` the whole stream runs as one fused dispatch; with it,
         chunks grow adaptively so an early limit stops the stream.
         """
-        tokens = np.asarray(tokens, np.int32)
-        if tokens.ndim != 1 or tokens.shape[0] < 1:
-            raise ValueError(f"tokens must be (n,) with n >= 1, got {tokens.shape}")
-        if n_rounds < 1:
-            raise ValueError(f"n_rounds must be >= 1, got {n_rounds}")
-        n = tokens.shape[0]
-        patterns = np.asarray(patterns, np.int32)
-        # bucket aligned to shards x rounds so every shard holds n_rounds
-        # equal chunks of the padded stream
-        multiple = self.n_shards * n_rounds
-        bucket = bucket_for(n, multiple=multiple, growth=self.bucket_growth)
-        chunk = bucket // multiple
-        spec = make_grep_spec(patterns, chunk, axis_name=self.axis_name,
-                              max_matches=max_matches)
-        view = self._view(("grep", patterns.tobytes(), chunk,
-                           max_matches, bucket))
-        if min_chunk is None:
-            min_chunk = n_rounds if max_matches is None else 1
-        if max_chunk is None:
-            max_chunk = n_rounds
+        with self._submission() as ticket:
+            tokens = np.asarray(tokens, np.int32)
+            if tokens.ndim != 1 or tokens.shape[0] < 1:
+                raise ValueError(f"tokens must be (n,) with n >= 1, got {tokens.shape}")
+            if n_rounds < 1:
+                raise ValueError(f"n_rounds must be >= 1, got {n_rounds}")
+            n = tokens.shape[0]
+            patterns = np.asarray(patterns, np.int32)
+            # bucket aligned to shards x rounds so every shard holds n_rounds
+            # equal chunks of the padded stream
+            multiple = self.n_shards * n_rounds
+            bucket = bucket_for(n, multiple=multiple, growth=self.bucket_growth)
+            chunk = bucket // multiple
+            spec = make_grep_spec(patterns, chunk, axis_name=self.axis_name,
+                                  max_matches=max_matches)
+            view = self._view(("grep", patterns.tobytes(), chunk,
+                               max_matches, bucket))
+            if min_chunk is None:
+                min_chunk = n_rounds if max_matches is None else 1
+            if max_chunk is None:
+                max_chunk = n_rounds
 
-        def make_gen(handle):
-            toks = np.full((bucket,), -1, np.int32)  # -1: matches no pattern
-            toks[:n] = tokens
-            init = {"hits": np.zeros((patterns.shape[0],), np.float32),
-                    "cursor": np.uint32(0)}
-            return self._run_chunks(spec, {"t": toks}, init,
-                                    handle, view, max_rounds=n_rounds,
-                                    min_chunk=min_chunk, max_chunk=max_chunk)
+            def make_gen(handle):
+                toks = np.full((bucket,), -1, np.int32)  # -1: matches no pattern
+                toks[:n] = tokens
+                init = {"hits": np.zeros((patterns.shape[0],), np.float32),
+                        "cursor": np.uint32(0)}
+                return self._run_chunks(spec, {"t": toks}, init,
+                                        handle, view, max_rounds=n_rounds,
+                                        min_chunk=min_chunk, max_chunk=max_chunk)
 
-        def finalize(res):
-            return {
-                "counts": np.asarray(res.state["hits"]),
-                "per_round": np.asarray(res.aux["round_hits"]),
-                "rounds": res.rounds_executed,
-                "halted": res.halted,
-            }
+            def finalize(res):
+                return {
+                    "counts": np.asarray(res.state["hits"]),
+                    "per_round": np.asarray(res.aux["round_hits"]),
+                    "rounds": res.rounds_executed,
+                    "halted": res.halted,
+                }
 
-        return self._submit("grep", n, bucket, n_rounds, make_gen, finalize,
-                            priority=priority)
+            return self._submit(ticket, "grep", n, bucket, n_rounds, make_gen, finalize,
+                                priority=priority)
