@@ -245,6 +245,10 @@ def bucket_pack(keys, bucket, values, n_buckets: int, capacity: int,
                 return_positions: bool = False):
     """Pack (key, value) pairs into a fixed (R, C, ...) per-destination buffer.
 
+    Items are stably sorted by bucket; an item's slot within its bucket is its
+    sorted index minus its bucket's start, and the R+1 bucket starts come from
+    one search of the sorted buckets, looked up by each item's bucket id.
+
     Args:
       keys:    (n,) int32; entries with key < 0 are padding (invalid).
       bucket:  (n,) int32 destination bucket in [0, n_buckets) for each item.
@@ -267,8 +271,11 @@ def bucket_pack(keys, bucket, values, n_buckets: int, capacity: int,
         order = jnp.argsort(b, stable=True)
         b_sorted = b[order]
         # position within bucket: i - first occurrence of this bucket value
-        first = jnp.searchsorted(b_sorted, b_sorted, side="left")
-        pos = jnp.arange(n, dtype=jnp.int32) - first.astype(jnp.int32)
+        starts = jnp.searchsorted(
+            b_sorted, jnp.arange(n_buckets + 1, dtype=b_sorted.dtype), side="left"
+        ).astype(jnp.int32)
+        first = jnp.take(starts, b_sorted)
+        pos = jnp.arange(n, dtype=jnp.int32) - first
         in_range = (b_sorted < n_buckets) & (pos < capacity)
         dest = jnp.where(in_range, b_sorted * capacity + pos, n_buckets * capacity)
         n_dropped = jnp.sum((b_sorted < n_buckets) & (pos >= capacity)).astype(jnp.int32)

@@ -1,5 +1,7 @@
 """Secure MapReduce engine: bucketing invariants, wordcount, k-means."""
 
+import re
+
 import numpy as np
 import pytest
 from hypothesis import given, settings
@@ -9,6 +11,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from repro import obs
 from repro.compat import make_mesh
 from repro.core.engine import MapReduceSpec, default_hash, identity_hash, run_mapreduce
 from repro.core.kmeans import generate_points, kmeans_fit, kmeans_step_ref, make_kmeans_step
@@ -156,6 +159,98 @@ def test_bucket_pack_intra_bucket_order_stable():
     bk = np.asarray(bk)
     np.testing.assert_array_equal(bk[0], np.array([3, 6, 7, -1], np.int32))
     np.testing.assert_array_equal(bk[1], np.array([5, 8, 4, -1], np.int32))
+
+
+def _bucket_pack_ref(keys, bucket, values, r, cap):
+    """numpy bucket_pack with each item's bucket start searched per item."""
+    n = keys.shape[0]
+    b = np.where(keys >= 0, bucket, r)
+    order = np.argsort(b, kind="stable")
+    b_sorted = b[order]
+    pos = np.arange(n) - np.searchsorted(b_sorted, b_sorted, side="left")
+    in_range = (b_sorted < r) & (pos < cap)
+    dest = np.where(in_range, b_sorted * cap + pos, r * cap)
+    n_dropped = int(((b_sorted < r) & (pos >= cap)).sum())
+
+    def scatter(x_sorted, fill):
+        out = np.full((r * cap + 1,) + x_sorted.shape[1:], fill, x_sorted.dtype)
+        out[dest[in_range]] = x_sorted[in_range]
+        return out[:-1].reshape((r, cap) + x_sorted.shape[1:])
+
+    positions = np.full((n,), r * cap, np.int32)
+    positions[order] = dest
+    out_values = {k: scatter(v[order], 0) for k, v in values.items()}
+    return scatter(keys[order], -1), out_values, n_dropped, positions
+
+
+def _bucket_pack_case(case, r, rng):
+    """(keys, bucket, capacity) for one named edge case over `r` buckets."""
+    n = 48
+    bucket = rng.integers(0, r, n).astype(np.int32)
+    keys = np.arange(n, dtype=np.int32)
+    if case == "invalid":
+        keys[rng.random(n) < 0.3] = -1
+        return keys, bucket, n
+    if case == "empty_buckets":  # only odd ids: bucket 0 and every even one empty
+        ids = np.arange(r)[np.arange(r) % 2 == (r > 1)]
+        return keys, rng.choice(ids, n).astype(np.int32), n
+    if case == "exact_capacity":  # every bucket holds exactly `cap` items
+        cap = 5
+        return (np.arange(r * cap, dtype=np.int32),
+                rng.permutation(np.repeat(np.arange(r, dtype=np.int32), cap)), cap)
+    if case == "overflow":  # most items to bucket 0, some invalid
+        bucket[rng.random(n) < 0.6] = 0
+        keys[rng.random(n) < 0.2] = -1
+        return keys, bucket, 3
+    assert case == "all_invalid"
+    return np.full((n,), -1, np.int32), bucket, 4
+
+
+@pytest.mark.parametrize("r", [1, 2, 4, 8, 33])
+@pytest.mark.parametrize("case", ["invalid", "empty_buckets", "exact_capacity",
+                                  "overflow", "all_invalid"])
+def test_bucket_pack_matches_per_item_search(case, r):
+    """Bucket starts expanded to items give, bit for bit, what a search per
+    item gives: keys, values, drops and positions."""
+    rng = np.random.default_rng(r * 101 + len(case))
+    keys, bucket, cap = _bucket_pack_case(case, r, rng)
+    n = keys.shape[0]
+    values = {"f": rng.standard_normal(n).astype(np.float32),
+              "v": rng.integers(-9, 9, (n, 3)).astype(np.int32)}
+    want_k, want_v, want_dropped, want_pos = _bucket_pack_ref(keys, bucket, values, r, cap)
+    packed = jax.jit(lambda k, b, v: bucket_pack(k, b, v, r, cap, return_positions=True))
+    got_k, got_v, got_dropped, got_pos = packed(keys, bucket, values)
+    np.testing.assert_array_equal(np.asarray(got_k), want_k)
+    for name in values:
+        np.testing.assert_array_equal(np.asarray(got_v[name]), want_v[name])
+    assert int(got_dropped) == want_dropped
+    np.testing.assert_array_equal(np.asarray(got_pos), want_pos)
+    if case == "exact_capacity":
+        assert want_dropped == 0 and (want_k >= 0).all()
+    if case == "overflow":
+        assert want_dropped > 0
+
+
+@pytest.mark.parametrize("r", [2, 4, 33])
+def test_bucket_pack_loops_hold_no_per_item_array(r):
+    """No op of bucket_pack inside a loop body has an n-element result: the
+    search runs over the R+1 bucket boundaries, not once per item."""
+    n = 1 << 16
+    spec = jax.ShapeDtypeStruct((n,), jnp.int32)
+    packed = jax.jit(lambda k, b: bucket_pack(k, b, k.astype(jnp.float32), r, n // r,
+                                              return_positions=True))
+    text = packed.lower(spec, spec).compile().as_text()
+    scoped, in_loop = [], []
+    for line in text.splitlines():
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        if op_name and obs.layer_of(op_name.group(1)) == obs.BUCKET_PACK:
+            scoped.append(line.strip())
+            if "/while/body/" in op_name.group(1):
+                in_loop.append(line.strip())
+    assert scoped, "no bucket_pack op in the compiled text"
+    per_item = [line for line in in_loop
+                if re.search(rf"[\[,]{n}[\],]", line.partition(" metadata=")[0])]
+    assert not per_item, per_item[:3]
 
 
 # --- wordcount ---------------------------------------------------------------
