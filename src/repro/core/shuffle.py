@@ -245,9 +245,14 @@ def bucket_pack(keys, bucket, values, n_buckets: int, capacity: int,
                 return_positions: bool = False):
     """Pack (key, value) pairs into a fixed (R, C, ...) per-destination buffer.
 
-    Items are stably sorted by bucket; an item's slot within its bucket is its
-    sorted index minus its bucket's start, and the R+1 bucket starts come from
-    one search of the sorted buckets, looked up by each item's bucket id.
+    Items are stably sorted by bucket in one `lax.sort` that carries the keys
+    and every (n,) value leaf along, so each bucket keeps its input order. One
+    search of the sorted buckets gives the R+1 bucket starts, and row j of
+    every output is the length-C window of its sorted leaf at bucket j's
+    start, cut to the bucket's count: contiguous copies, no per-item scatter.
+    A leaf with trailing dims cannot ride the sort; an item index rides it
+    instead, and the leaf's rows are gathered by that index's windows, from
+    which `positions` is scattered too.
 
     Args:
       keys:    (n,) int32; entries with key < 0 are padding (invalid).
@@ -266,39 +271,44 @@ def bucket_pack(keys, bucket, values, n_buckets: int, capacity: int,
     """
     with jax.named_scope(obs.BUCKET_PACK):
         n = keys.shape[0]
-        valid = keys >= 0
-        b = jnp.where(valid, bucket, n_buckets)  # invalid items sort last
-        order = jnp.argsort(b, stable=True)
-        b_sorted = b[order]
-        # position within bucket: i - first occurrence of this bucket value
+        leaves, treedef = jax.tree.flatten(values)
+        b = jnp.where(keys >= 0, bucket, n_buckets)  # invalid items sort last
+        flat = [i for i, v in enumerate(leaves) if v.ndim == 1]
+        wide = any(v.ndim > 1 and 0 not in v.shape[1:] for v in leaves)
+        index = [jnp.arange(n, dtype=jnp.int32)] if wide or return_positions else []
+        b_sorted, keys_sorted, *rest = lax.sort(
+            [b, keys, *(leaves[i] for i in flat), *index], num_keys=1, is_stable=True)
+        sorted_flat = dict(zip(flat, rest))
         starts = jnp.searchsorted(
             b_sorted, jnp.arange(n_buckets + 1, dtype=b_sorted.dtype), side="left"
         ).astype(jnp.int32)
-        first = jnp.take(starts, b_sorted)
-        pos = jnp.arange(n, dtype=jnp.int32) - first
-        in_range = (b_sorted < n_buckets) & (pos < capacity)
-        dest = jnp.where(in_range, b_sorted * capacity + pos, n_buckets * capacity)
-        n_dropped = jnp.sum((b_sorted < n_buckets) & (pos >= capacity)).astype(jnp.int32)
+        counts = starts[1:] - starts[:-1]
+        n_dropped = jnp.sum(jnp.maximum(counts - capacity, 0)).astype(jnp.int32)
+        filled = jnp.arange(capacity, dtype=jnp.int32) < counts[:, None]
 
-        def scatter(x_sorted, fill):
-            if any(d == 0 for d in x_sorted.shape[1:]):
-                # Zero-size trailing dims (e.g. a (n, 0) per-item leaf): the
-                # n_buckets*capacity+1 overflow-slot scatter below degenerates —
-                # there are no elements to place, only shapes to produce — so
-                # return the empty fixed-shape buffer directly instead of
-                # emitting a 0-element XLA scatter.
-                return jnp.zeros((n_buckets, capacity) + x_sorted.shape[1:], x_sorted.dtype)
-            out = jnp.full((n_buckets * capacity + 1,) + x_sorted.shape[1:], fill, x_sorted.dtype)
-            out = out.at[dest].set(x_sorted)
-            return out[:-1].reshape((n_buckets, capacity) + x_sorted.shape[1:])
+        def window(x_sorted, fill):
+            # padded by C so that no bucket's window is clamped at the end
+            padded = jnp.concatenate([x_sorted, jnp.full((capacity,), fill, x_sorted.dtype)])
+            rows = jax.vmap(lambda s: lax.dynamic_slice_in_dim(padded, s, capacity))(starts[:-1])
+            return jnp.where(filled, rows, fill)
 
-        out_keys = scatter(keys[order], jnp.int32(-1))
-        out_values = jax.tree.map(lambda v: scatter(v[order], jnp.zeros((), v.dtype)), values)
+        out_keys = window(keys_sorted, jnp.int32(-1))
+        src = window(rest[-1], jnp.int32(0)) if index else None  # input item of each slot
+
+        def pack_leaf(i, v):
+            if i in sorted_flat:
+                return window(sorted_flat[i], jnp.zeros((), v.dtype))
+            if 0 in v.shape[1:]:  # zero-size trailing dims: nothing to place
+                return jnp.zeros((n_buckets, capacity) + v.shape[1:], v.dtype)
+            mask = filled.reshape(filled.shape + (1,) * (v.ndim - 1))
+            return jnp.where(mask, v[src], jnp.zeros((), v.dtype))
+
+        out_values = jax.tree.unflatten(treedef, [pack_leaf(i, v) for i, v in enumerate(leaves)])
         if not return_positions:
             return out_keys, out_values, n_dropped
-        positions = jnp.full((n,), n_buckets * capacity, jnp.int32).at[order].set(
-            dest.astype(jnp.int32)
-        )
+        slots = jnp.arange(n_buckets * capacity, dtype=jnp.int32).reshape(n_buckets, capacity)
+        positions = jnp.full((n,), n_buckets * capacity, jnp.int32).at[
+            jnp.where(filled, src, n)].set(slots, mode="drop")
         return out_keys, out_values, n_dropped, positions
 
 

@@ -206,25 +206,33 @@ def _bucket_pack_case(case, r, rng):
     return np.full((n,), -1, np.int32), bucket, 4
 
 
+@pytest.mark.parametrize("return_positions", [False, True])
 @pytest.mark.parametrize("r", [1, 2, 4, 8, 33])
 @pytest.mark.parametrize("case", ["invalid", "empty_buckets", "exact_capacity",
                                   "overflow", "all_invalid"])
-def test_bucket_pack_matches_per_item_search(case, r):
-    """Bucket starts expanded to items give, bit for bit, what a search per
-    item gives: keys, values, drops and positions."""
+def test_bucket_pack_matches_per_item_search(case, r, return_positions):
+    """Bucket windows of the sorted leaves give, bit for bit, what a search
+    per item gives: keys, values, drops and positions. 1-D leaves ride the
+    sort; the (n, 3) leaf is gathered by row."""
     rng = np.random.default_rng(r * 101 + len(case))
     keys, bucket, cap = _bucket_pack_case(case, r, rng)
     n = keys.shape[0]
     values = {"f": rng.standard_normal(n).astype(np.float32),
+              "i": rng.integers(-9, 9, n).astype(np.int32),
               "v": rng.integers(-9, 9, (n, 3)).astype(np.int32)}
     want_k, want_v, want_dropped, want_pos = _bucket_pack_ref(keys, bucket, values, r, cap)
-    packed = jax.jit(lambda k, b, v: bucket_pack(k, b, v, r, cap, return_positions=True))
-    got_k, got_v, got_dropped, got_pos = packed(keys, bucket, values)
+    packed = jax.jit(lambda k, b, v: bucket_pack(k, b, v, r, cap,
+                                                 return_positions=return_positions))
+    got = packed(keys, bucket, values)
+    assert len(got) == (4 if return_positions else 3)
+    got_k, got_v, got_dropped = got[:3]
     np.testing.assert_array_equal(np.asarray(got_k), want_k)
     for name in values:
         np.testing.assert_array_equal(np.asarray(got_v[name]), want_v[name])
+        assert got_v[name].dtype == want_v[name].dtype
     assert int(got_dropped) == want_dropped
-    np.testing.assert_array_equal(np.asarray(got_pos), want_pos)
+    if return_positions:
+        np.testing.assert_array_equal(np.asarray(got[3]), want_pos)
     if case == "exact_capacity":
         assert want_dropped == 0 and (want_k >= 0).all()
     if case == "overflow":
@@ -250,6 +258,40 @@ def test_bucket_pack_loops_hold_no_per_item_array(r):
     assert scoped, "no bucket_pack op in the compiled text"
     per_item = [line for line in in_loop
                 if re.search(rf"[\[,]{n}[\],]", line.partition(" metadata=")[0])]
+    assert not per_item, per_item[:3]
+
+
+def _n_elements(shape: str) -> int:
+    return int(np.prod([int(d) for d in shape.split(",") if d]))
+
+
+@pytest.mark.parametrize("r", [1, 4, 33])
+def test_bucket_pack_has_no_per_item_gather_or_scatter(r):
+    """1-D leaves ride the sort and each bucket's row is one window: no op of
+    bucket_pack scatters, and none gathers single items into an n-element
+    result (only the C-wide windows and the R+1 boundary search gather)."""
+    n = 1 << 16
+    spec = jax.ShapeDtypeStruct((n,), jnp.int32)
+    packed = jax.jit(lambda k, b: bucket_pack(
+        k, b, {"f": k.astype(jnp.float32), "i": k + 1}, r, n // r))
+    text = packed.lower(spec, spec).compile().as_text()
+    scoped, scatters, per_item = [], [], []
+    for line in text.splitlines():
+        op_name = re.search(r'op_name="([^"]*)"', line)
+        if not op_name or obs.layer_of(op_name.group(1)) != obs.BUCKET_PACK:
+            continue
+        scoped.append(line.strip())
+        op = re.search(r"= (\w+)\[([\d,]*)\][^ ]* (\w[\w-]*)\(", line)
+        if op is None:
+            continue
+        if op.group(3) == "scatter":
+            scatters.append(line.strip())
+        sizes = re.search(r"slice_sizes=\{([\d,]*)\}", line)
+        if (op.group(3) == "gather" and sizes and sizes.group(1).split(",")[0] == "1"
+                and _n_elements(op.group(2)) >= n):
+            per_item.append(line.strip())
+    assert scoped, "no bucket_pack op in the compiled text"
+    assert not scatters, scatters[:3]
     assert not per_item, per_item[:3]
 
 
